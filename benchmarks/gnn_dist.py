@@ -1,6 +1,10 @@
 """Distributed-GNN benchmark: the mesh-sharded partition-parallel engine
 vs the single-device full-graph engine at equal compression config.
 
+A CPU-only tool: it forces virtual host devices and times the CPU
+backend, so no number it writes is a device number.  On a TPU host the
+four-chip mesh phase of ``chip_smoke.py --four-chips`` is the check.
+
 Run standalone (``PYTHONPATH=src python benchmarks/gnn_dist.py``) this
 module forces an 8-device host platform *before* jax initializes, so the
 4-partition arm actually shards over 4 devices with a live halo exchange

@@ -116,8 +116,7 @@ def _dispatched_compressor_rows(impls=None):
 
 def fused_cases():
     """(tag, m, d, n, bits, group_size, levels) shapes the fused rows
-    sweep — also the shapes ``refresh_experiments.py --bench`` feeds the
-    tile autotuner, so the recorded rows use the tiles training gets."""
+    sweep, at the tiles the autotuner picks for training."""
     return [
         ("b2_g256", 4096, 256, 256, 2, 256, None),
         ("b4_g128", 2048, 256, 256, 4, 128, None),

@@ -2,17 +2,20 @@
 three-term table (compute / memory / collective, seconds per step per
 device) with the dominant bottleneck per (arch × shape × mesh).
 
-Hardware constants (TPU v5e-class, per chip):
-  197 TFLOP/s bf16 · 819 GB/s HBM · ~50 GB/s/link ICI
+Hardware constants come from the ``device_kind``-keyed peak table
+(:mod:`repro.kernels.peaks`); the dry run models the v5e production mesh.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.kernels.peaks import TARGET_KIND, chip_peaks
+
+_PEAKS = chip_peaks(TARGET_KIND)
+PEAK_FLOPS = _PEAKS.flops
+HBM_BW = _PEAKS.hbm_bytes_per_s
+ICI_BW = _PEAKS.ici_bytes_per_s
 
 RESULTS = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 

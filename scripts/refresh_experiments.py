@@ -9,11 +9,13 @@ Default: the §Roofline table in EXPERIMENTS.md from results/dryrun.
 benchmark suites (each lowers explicit
 :class:`repro.engine.plan.ExecutionPlan` objects through ``engine.run``,
 so the refreshed numbers describe exactly what the engine executes) plus
-the kernel-throughput sweep (which records the fused matmul-quant rows),
-and re-measure the fused tile autotune cache
-(``results/autotune/fused_tiles.json``) over the benchmark shapes.
+the kernel-throughput sweep (which records the fused matmul-quant rows).
 Run this on the CI-class machine whenever an intentional change moves
 the columns ``scripts/bench_regression.py`` gates.
+
+A CPU-only tool: the suites time the CPU backend, and ``gnn_dist`` runs
+in a subprocess that forces virtual host devices, which a TPU host's
+one-process-per-chip rule forbids.
 """
 from __future__ import annotations
 
@@ -41,17 +43,10 @@ def fmt(x, p=3):
 
 def refresh_bench_baselines():
     """Re-run the engine-routed bench suites; they rewrite the committed
-    BENCH_*.json in place (the bench-regression gate's baselines).  The
-    fused tile autotune cache is re-measured first so the kernel sweep's
-    fused rows record the tiles training would actually dispatch with."""
+    BENCH_*.json in place (the bench-regression gate's baselines)."""
     from benchmarks import (autoprec, gnn_batched, kernel_throughput,
                             offload, serve)
-    from repro.kernels import autotune
 
-    print("re-measuring fused tile autotune cache ...")
-    cache = autotune.autotune([(m, d, n, bits, g) for (_, m, d, n, bits, g, _)
-                               in kernel_throughput.fused_cases()])
-    print(f"  {len(cache)} cache entries -> {autotune.cache_path()}")
     for tag, fn in [("gnn_batched", gnn_batched.main),
                     ("autoprec", autoprec.main), ("offload", offload.main),
                     ("serve", serve.main),

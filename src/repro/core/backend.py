@@ -16,7 +16,7 @@ noise is a counter hash and the strided pack layout is shared; see
 ``tests/test_backend.py`` for the parity gate).  Random projection is a
 float matmul, so impls agree to float tolerance, not bit-exactly — RP
 routing is therefore best-effort: shapes that don't meet the Pallas tile
-constraints silently use the jnp matmul.
+constraints use the jnp matmul (:func:`rp_kernel_unsupported` says why).
 
 Static-argument discipline: VM level tables are normalized to *hashable
 tuples of python floats* before they reach ``pallas_call`` (the kernels
@@ -187,6 +187,25 @@ def supports_fused(shape, bits: int, group_size: int, levels=None) -> bool:
     return fused_unsupported(shape, bits, group_size, levels) is None
 
 
+def fused_decline_reason(fused: str, impl: str, shape, bits: int,
+                         group_size: int, levels=None,
+                         rp_ratio: int = 0) -> str | None:
+    """Why :func:`route_fused` keeps the unfused two-pass spelling for
+    this layer (None = it fuses)."""
+    if fused == "off":
+        return "fused='off'"
+    reason = fused_unsupported(shape, bits, group_size, levels)
+    if reason is None and rp_ratio > 1:
+        reason = (f"rp_ratio={rp_ratio} projects before quantization; the "
+                  "fused epilogue quantizes the matmul operand itself")
+    if reason is None and fused == "auto":
+        concrete = resolve_impl(impl)
+        if concrete != "pallas":
+            reason = (f"fused='auto' fuses on the Pallas backend only; impl "
+                      f"resolves to {concrete!r}")
+    return reason
+
+
 def route_fused(fused: str, impl: str, shape, bits: int, group_size: int,
                 levels=None, rp_ratio: int = 0) -> str | None:
     """Concrete impl the fused matmul-quant pair should run on, or None
@@ -202,21 +221,13 @@ def route_fused(fused: str, impl: str, shape, bits: int, group_size: int,
     """
     if fused not in VALID_FUSED:
         raise ValueError(f"fused={fused!r} not in {VALID_FUSED}")
-    if fused == "off":
-        return None
-    concrete = resolve_impl(impl)
-    reason = fused_unsupported(shape, bits, group_size, levels)
-    if reason is None and rp_ratio > 1:
-        reason = (f"rp_ratio={rp_ratio} projects before quantization; the "
-                  "fused epilogue quantizes the matmul operand itself")
+    reason = fused_decline_reason(fused, impl, shape, bits, group_size,
+                                  levels, rp_ratio)
+    if reason is None:
+        return resolve_impl(impl)
     if fused == "on":
-        if reason is not None:
-            raise ValueError(f"fused='on' cannot run this config: {reason}")
-        return concrete
-    # auto: fuse only on the real kernel path
-    if reason is not None or concrete != "pallas":
-        return None
-    return concrete
+        raise ValueError(f"fused='on' cannot run this config: {reason}")
+    return None
 
 
 def rp_kernel_unsupported(d_in: int, d_out: int, *, tn: int = 128,
@@ -233,7 +244,8 @@ def route_rp(impl: str, d_in: int, d_out: int, *, tn: int = 128,
 
     RP across impls agrees to float tolerance only (matmul accumulation
     order), so forcing a kernel here buys no bit-parity; shapes off the
-    tile grid quietly take the reference matmul.
+    tile grid take the reference matmul, and
+    :func:`rp_kernel_unsupported` says why.
     """
     concrete = resolve_impl(impl)
     if concrete == "jnp":
@@ -249,9 +261,10 @@ def to_blocks(x: jnp.ndarray, group_size: int) -> tuple[jnp.ndarray, int]:
 
     The *within-block* tail is padded by replicating the last element
     (cannot widen the final block's [min, max] envelope — zeros would).
-    Whole-row padding to the kernel tile (``ops._pad_rows``) happens below
-    this layer and only ever appends fake blocks that are sliced off, so
-    real block stats are never contaminated.
+    Whole-row padding to the kernel tile (the kernel wrappers'
+    ``pad_to_multiple``) happens below this layer and only ever appends
+    fake blocks that are sliced off, so real block stats are never
+    contaminated.
     """
     return quantmod.group_reshape(x, group_size)
 

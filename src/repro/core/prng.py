@@ -52,12 +52,19 @@ def uniform_from_counter(seed, counter: jnp.ndarray) -> jnp.ndarray:
     hs = hash_u32(seed.reshape(1) if seed.ndim == 0 else seed)
     mixed = hash_u32((counter.astype(jnp.uint32) * _GOLDEN).astype(jnp.uint32)
                      + hs)
-    return (mixed >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # via int32: Mosaic has no uint32 -> float32 cast, and a 24-bit draw
+    # is exact in both
+    return ((mixed >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def rademacher_from_counter(seed, counter: jnp.ndarray) -> jnp.ndarray:
-    """±1 int8 signs from (scalar seed, uint32 counter array)."""
+    """±1 float32 signs from (scalar seed, uint32 counter array).
+
+    Selected in float32: Mosaic cannot multiply int8 vectors, so integer
+    sign arithmetic would not lower inside the RP kernel.
+    """
     seed = jnp.asarray(seed, jnp.uint32)
     mixed = hash_u32((counter.astype(jnp.uint32) * _GOLDEN).astype(jnp.uint32)
                      + hash_u32(seed.reshape(1) + jnp.uint32(_RADEMACHER_SALT)))
-    return (jnp.int8(1) - (jnp.int8(2) * (mixed & 1).astype(jnp.int8)))
+    return jnp.where((mixed & 1) == 0, jnp.float32(1.0), jnp.float32(-1.0))

@@ -1,22 +1,22 @@
-"""Tile-size autotuning for the fused matmul-quant kernels.
+"""Tile sizes for the fused matmul-quant kernels, computed in code.
 
-Two layers:
+A **roofline model** ranks every legal ``(tm, tn)`` candidate of the
+fused forward by predicted time — max of the compute term and the HBM
+term, where larger ``tm`` cuts repeated ``w`` reads and larger ``tn``
+cuts repeated ``x`` reads — subject to a VMEM budget.  The rates come
+from the ``device_kind``-keyed peak table (:mod:`repro.kernels.peaks`);
+an unlisted kind raises rather than ranking against another chip.
 
-* a **roofline model** (hardware constants shared with
-  :mod:`benchmarks.roofline` when importable) ranks every legal
-  ``(tm, tn)`` candidate by predicted time — max of the compute term and
-  the HBM term, where larger ``tm`` cuts repeated ``w`` reads and larger
-  ``tn`` cuts repeated ``x`` reads, subject to a VMEM budget;
-* an optional **measurement pass** (:func:`autotune`) times the real
-  kernel over the model's top candidates and persists the winner in a
-  JSON cache keyed on ``(shape, bits, group_size, backend)`` —
-  ``results/autotune/fused_tiles.json`` by default, overridable via
-  ``REPRO_AUTOTUNE_CACHE``.
+The fused backward takes the largest row tile whose working set fits the
+same budget: ``tile_rows == m`` (one tile, the bit-exact order) while it
+fits, else the largest fixed row tile, with ragged rows zero-padded by
+the kernel wrapper.  Fewer row tiles mean fewer ``(D, TN)`` partials
+written and tree-reduced, so on the roofline the largest fitting tile
+is always the fastest.
 
-:func:`get_tiles` is the trace-time read path the dispatch layer uses:
-cache hit → cached tiles; miss → roofline-best default.  It never
-measures (measurement re-jits; ``scripts/refresh_experiments.py --bench``
-refreshes the cache deliberately).
+:func:`get_tiles` is the trace-time read path the dispatch layer uses.
+Nothing is read from disk, so a fresh checkout picks the same tiles as
+the machine that wrote it.
 
 Legality: a row tile must own whole quantization blocks —
 ``(tm * d) % group_size == 0`` — which is the same invariant
@@ -24,58 +24,63 @@ Legality: a row tile must own whole quantization blocks —
 """
 from __future__ import annotations
 
-import functools
-import json
-import math
-import os
-import pathlib
+from repro.kernels.fused_matmul import row_tile_step
+from repro.kernels.peaks import TARGET_KIND, chip_peaks
 
-_REPO = pathlib.Path(__file__).resolve().parents[3]
-_DEFAULT_CACHE = _REPO / "results" / "autotune" / "fused_tiles.json"
+#: Mosaic's default scoped-VMEM limit per kernel on v5e (bytes).  The
+#: working-set models below must stay under it or the compile fails.
+VMEM_BUDGET = 16 << 20
 
-try:  # single source for the hardware constants when the bench dir is on path
-    from benchmarks.roofline import HBM_BW, PEAK_FLOPS
-except Exception:  # pragma: no cover - library use without the bench dir
-    PEAK_FLOPS = 197e12
-    HBM_BW = 819e9
+#: Row tiles the fused backward tries, largest first, after ``m`` itself.
+BWD_ROW_TILES = (4096, 2048, 1024, 512, 256, 128)
 
-#: VMEM working-set budget per kernel invocation (bytes); v5e has 128 MB
-#: of VMEM but leave generous headroom for double-buffering + the packed
-#: epilogue outputs.
-VMEM_BUDGET = 8 << 20
+#: VMEM bytes per tile element for the quantize/dequantize temporaries
+#: (normalized values, noise, codes, the block regroup and the unpacked
+#: code planes).  Fit to described-v5e compiles at 169,343 rows: every
+#: tile this model admits compiled, and every tile Mosaic refused
+#: (bits 2 and 8, G 128 and 256, D 128 to 512) is over the budget.
+_TEMP_BYTES_PER_ELEM = 48
 
 
-def cache_path() -> pathlib.Path:
-    return pathlib.Path(os.environ.get("REPRO_AUTOTUNE_CACHE",
-                                       str(_DEFAULT_CACHE)))
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane vector rows."""
+    return -(-n // 128) * 128
 
 
-def _cache_key(kind: str, m: int, d: int, n: int, bits: int,
-               group_size: int, backend: str) -> str:
-    return f"{kind}/{m}x{d}x{n}/b{bits}/g{group_size}/{backend}"
+def _block_rows_bytes(n_blocks: int, bits: int, group_size: int) -> int:
+    """Packed words plus the (zero, range) columns of ``n_blocks`` blocks:
+    each is a lane-sparse row padded to whole 128-lane rows in VMEM."""
+    return 4 * n_blocks * (_lanes(group_size * bits // 32) + 2 * 128)
 
 
-@functools.lru_cache(maxsize=1)
-def _load_cache() -> dict:
-    p = cache_path()
-    if p.exists():
-        try:
-            return json.loads(p.read_text())
-        except Exception:
-            return {}
-    return {}
+def tile_device_kind() -> str:
+    """The ``device_kind`` tiles are ranked for: the attached TPU, or the
+    deployment target off-TPU (interpret mode then runs the chip's tiles)."""
+    import jax
+
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else TARGET_KIND
 
 
-def invalidate_cache() -> None:
-    _load_cache.cache_clear()
+def fwd_vmem_bytes(tm: int, d: int, tn: int, bits: int,
+                   group_size: int) -> int:
+    """Fused-forward working set: double-buffered operand, weight and
+    output tiles and stash rows, plus the epilogue's temporaries."""
+    io = (4 * (tm * d + d * _lanes(tn) + tm * _lanes(tn))
+          + _block_rows_bytes(tm * d // group_size, bits, group_size))
+    return 2 * io + _TEMP_BYTES_PER_ELEM * tm * d
 
 
-def row_tile_step(d: int, group_size: int) -> int:
-    """Smallest row-tile increment keeping whole blocks per tile."""
-    return group_size // math.gcd(group_size, d)
+def bwd_vmem_bytes(tile_rows: int, d: int, tn: int, bits: int,
+                   group_size: int) -> int:
+    """Fused-backward working set: double-buffered stash rows, gradient
+    and partial tiles, plus the prologue's temporaries."""
+    io = (4 * (tile_rows * _lanes(tn) + d * _lanes(tn))
+          + _block_rows_bytes(tile_rows * d // group_size, bits, group_size))
+    return 2 * io + _TEMP_BYTES_PER_ELEM * tile_rows * d
 
 
-def fwd_candidates(m: int, d: int, n: int, group_size: int):
+def fwd_candidates(m: int, d: int, n: int, group_size: int, bits: int):
     """Legal (tm, tn) pairs for the fused forward, VMEM-feasible."""
     step = row_tile_step(d, group_size)
     out = []
@@ -84,153 +89,57 @@ def fwd_candidates(m: int, d: int, n: int, group_size: int):
         tm = min(tm, ((m + step - 1) // step) * step)
         for tn in (128, 256, 512):
             tn = min(tn, n)
-            vmem = 4 * (tm * d + d * tn + tm * tn + tm * d // 8)
-            if vmem <= VMEM_BUDGET and (tm, tn) not in out:
+            if (fwd_vmem_bytes(tm, d, tn, bits, group_size) <= VMEM_BUDGET
+                    and (tm, tn) not in out):
                 out.append((tm, tn))
     return out or [(step, min(128, n))]
 
 
 def fwd_roofline_us(m: int, d: int, n: int, tm: int, tn: int,
-                    bits: int = 2) -> float:
+                    bits: int = 2, device_kind: str = TARGET_KIND) -> float:
     """Predicted fused-forward time (µs) for one (tm, tn) choice."""
+    peaks = chip_peaks(device_kind)
     gi = -(-m // tm)
     gj = -(-n // tn)
     flops = 2.0 * m * d * n
     # x read once per N tile, w once per M tile, y written once, packed out
     bytes_moved = (4.0 * m * d * gj + 4.0 * d * n * gi + 4.0 * m * n
                    + m * d * bits / 8 + 8.0 * m * d / 64)
-    return max(flops / PEAK_FLOPS, bytes_moved / HBM_BW) * 1e6
+    return max(flops / peaks.flops, bytes_moved / peaks.hbm_bytes_per_s) * 1e6
 
 
-def bwd_candidates(m: int, d: int, n: int, group_size: int):
-    """Legal (tile_rows, tn) pairs for the fused backward.
+def bwd_candidates(m: int, d: int, n: int, group_size: int, bits: int):
+    """Legal (tile_rows, tn) pairs for the fused backward, largest row
+    tile first; every one fits the VMEM budget.
 
-    ``tile_rows = m`` (single tile) leads — it is the bit-exact
-    configuration; row-tiled candidates follow for VMEM-constrained
-    deployment shapes.
+    ``tile_rows = m`` (single tile, the bit-exact order) leads whenever
+    it fits; fixed row tiles follow, and the kernel wrapper pads ragged
+    ``m`` up to a whole number of them.
     """
     step = row_tile_step(d, group_size)
+    tn = min(128, n)
     out = []
-    for tile_rows in (m, 512, 256, 128):
-        if tile_rows > m or tile_rows % step or m % tile_rows:
+    for tile_rows in (m, *BWD_ROW_TILES):
+        if tile_rows > m or tile_rows % step:
             continue
-        for tn in (128, 256):
-            tn = min(tn, n)
-            vmem = 4 * (tile_rows * d + tile_rows * tn + d * tn
-                        + tile_rows * d // 8)
-            if vmem <= VMEM_BUDGET or tile_rows == m:
-                if (tile_rows, tn) not in out:
-                    out.append((tile_rows, tn))
-    return out or [(m, min(128, n))]
+        if (bwd_vmem_bytes(tile_rows, d, tn, bits, group_size)
+                <= VMEM_BUDGET
+                and (tile_rows, tn) not in out):
+            out.append((tile_rows, tn))
+    return out or [(step, tn)]
 
 
 def get_tiles(kind: str, m: int, d: int, n: int, bits: int,
-              group_size: int, backend: str):
-    """Tiles for one fused call: cache hit, else roofline-best legal pick.
+              group_size: int, device_kind: str):
+    """Tiles for one fused call on ``device_kind``.
 
-    kind "fwd" → (tm, tn); kind "bwd" → (tile_rows, tn) with tile_rows
-    == m outside the cache (the bit-exact default).
+    kind "fwd" → roofline-best (tm, tn); kind "bwd" → (tile_rows, tn)
+    with the largest row tile that fits VMEM.  Raises ``ValueError`` for
+    a ``device_kind`` missing from the peak table.
     """
-    from repro.obs.metrics import get_metrics
-
-    hit = _load_cache().get(_cache_key(kind, m, d, n, bits, group_size,
-                                       backend))
-    if hit:
-        get_metrics().counter("autotune/cache_hit").inc()
-        return tuple(hit)
-    get_metrics().counter("autotune/cache_miss").inc()
+    chip_peaks(device_kind)
     if kind == "bwd":
-        return m, min(128, n)
-    cands = fwd_candidates(m, d, n, group_size)
-    best = min(cands, key=lambda c: fwd_roofline_us(m, d, n, *c, bits=bits))
-    return best
-
-
-def autotune(cases, *, impl: str = "auto", repeats: int = 3,
-             write: bool = True) -> dict:
-    """Measure the fused kernels over roofline-ranked candidates and
-    persist the winners.
-
-    ``cases``: iterable of ``(m, d, n, bits, group_size)``.  Returns the
-    updated cache dict.  Measurement runs whatever ``impl`` resolves to
-    on this host (interp on CPU), so a cache written on CPU carries
-    interp-mode winners; the backend component of the key keeps TPU and
-    CPU entries separate.
-    """
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    backend = jax.default_backend()
-    cache = dict(_load_cache())
-    if ops._resolve(impl) == "jnp":
-        # the jnp reference composition never tiles — "measuring" it would
-        # persist pure timing noise as winners.  Record the same roofline
-        # defaults the trace-time read path would pick, so a CPU-refreshed
-        # cache is consistent instead of misleading.
-        for (m, d, n, bits, group_size) in cases:
-            cache[_cache_key("fwd", m, d, n, bits, group_size, backend)] = \
-                list(min(fwd_candidates(m, d, n, group_size),
-                         key=lambda c: fwd_roofline_us(m, d, n, *c,
-                                                       bits=bits)))
-            cache[_cache_key("bwd", m, d, n, bits, group_size, backend)] = \
-                [m, min(128, n)]
-        if write:
-            p = cache_path()
-            p.parent.mkdir(parents=True, exist_ok=True)
-            p.write_text(json.dumps(cache, indent=2, sort_keys=True))
-            invalidate_cache()
-        return cache
-    for (m, d, n, bits, group_size) in cases:
-        x = jax.random.normal(jax.random.PRNGKey(0), (m, d), jnp.float32)
-        w = jax.random.normal(jax.random.PRNGKey(1), (d, n), jnp.float32)
-        g = jax.random.normal(jax.random.PRNGKey(2), (m, n), jnp.float32)
-
-        def _time(f):
-            jax.block_until_ready(f())
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                jax.block_until_ready(f())
-            return (time.perf_counter() - t0) / repeats * 1e6
-
-        best_f, best_f_us = None, float("inf")
-        for (tm, tn) in fwd_candidates(m, d, n, group_size):
-            us = _time(lambda tm=tm, tn=tn: ops.matmul_quantize_packed(
-                x, w, bits, 7, None, impl=impl, group_size=group_size,
-                tm=tm, tn=tn))
-            if us < best_f_us:
-                best_f, best_f_us = (tm, tn), us
-        cache[_cache_key("fwd", m, d, n, bits, group_size, backend)] = \
-            list(best_f)
-
-        _, packed, zero, rng = ops.matmul_quantize_packed(
-            x, w, bits, 7, None, impl=impl, group_size=group_size)
-        cands = bwd_candidates(m, d, n, group_size)
-        best_b, best_b_us = None, float("inf")
-        best_single, best_single_us = None, float("inf")
-        for (tr, tn) in cands:
-            us = _time(lambda tr=tr, tn=tn: ops.dequant_matmul_packed(
-                packed, zero, rng, g, bits, group_size, d, None,
-                impl=impl, tile_rows=tr, tn=tn))
-            if tr == m and us < best_single_us:
-                best_single, best_single_us = (tr, tn), us
-            if us < best_b_us:
-                best_b, best_b_us = (tr, tn), us
-        if (best_b[0] != m and best_single is not None
-                and not best_b_us < 0.9 * best_single_us):
-            # the row-tiled backward is deterministic (fixed-order tree
-            # reduction) but not bit-equal to the single-tile order —
-            # persist a split-accumulation winner only on a clear (>10%)
-            # measured win, never on timing noise
-            best_b = best_single
-        cache[_cache_key("bwd", m, d, n, bits, group_size, backend)] = \
-            list(best_b)
-    if write:
-        p = cache_path()
-        p.parent.mkdir(parents=True, exist_ok=True)
-        p.write_text(json.dumps(cache, indent=2, sort_keys=True))
-        invalidate_cache()
-    return cache
+        return bwd_candidates(m, d, n, group_size, bits)[0]
+    cands = fwd_candidates(m, d, n, group_size, bits)
+    return min(cands, key=lambda c: fwd_roofline_us(
+        m, d, n, *c, bits=bits, device_kind=device_kind))

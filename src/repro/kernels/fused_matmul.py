@@ -25,13 +25,12 @@ shared with :mod:`repro.kernels.quant_blockwise` (whose ``_sr_codes`` /
 ``(TM, D) @ (D, TN)`` keeps the full contraction in one dot, so ``y`` is
 the same per-element reduction as the unfused ``x @ w``.  The backward
 contraction over rows is exact when run as a single row tile
-(``tile_rows == M``, the default everywhere bit-parity is gated).
-Tiling rows splits the accumulation into per-tile partials combined by a
-**fixed-order pairwise tree** (:func:`_tree_sum`): bit-stable
-run-to-run and across backends/grid schedules (the order is a pure
-function of the tile count), agreeing with the single-tile order to
-float tolerance — so the autotuner may pick tiled backward candidates
-off-TPU too, whenever they actually win.
+(``tile_rows == M``, which the autotuner picks whenever it fits VMEM —
+every shape where bit-parity is gated).  Tiling rows splits the
+accumulation into per-tile partials combined by a **fixed-order pairwise
+tree** (:func:`_tree_sum`): bit-stable run-to-run and across
+backends/grid schedules (the order is a pure function of the tile
+count), agreeing with the single-tile order to float tolerance.
 
 Eligibility (quantization blocks must coincide with whole row tiles) is
 owned by :func:`repro.core.backend.supports_fused`; these kernels assert
@@ -40,6 +39,7 @@ the same invariants.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +47,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.prng import uniform_from_counter
 from repro.core.quant import EPS as _EPS
-from repro.kernels.quant_blockwise import _levels_value, _sr_codes
+from repro.kernels.quant_blockwise import (_levels_value, _sr_codes,
+                                          pad_to_multiple)
 
 
 def _quant_epilogue(x, seed, row0_blocks, bits: int, group_size: int,
@@ -99,6 +100,18 @@ def _matmul_quant_kernel(seed_ref, x_ref, w_ref, y_ref, packed_ref,
         rng_ref[...] = rng
 
 
+def row_tile_step(d: int, group_size: int) -> int:
+    """Smallest row-tile increment keeping whole blocks per tile."""
+    return group_size // math.gcd(group_size, d)
+
+
+def _whole_block_rows(tile: int, d: int, group_size: int) -> int:
+    """``tile`` rounded down to whole quantization blocks (at least one
+    step), the legality every fused row tile needs."""
+    step = row_tile_step(d, group_size)
+    return max(step, (tile // step) * step)
+
+
 def _build_matmul_quant(m, d, n, bits, group_size, levels, tm, tn,
                         interpret):
     assert m % tm == 0 and n % tn == 0, (m, n, tm, tn)
@@ -143,14 +156,22 @@ def matmul_quant_call(x2d, w, bits: int, seed, levels=None, *,
     Returns ``(y (M, N) f32, packed (M*D/G, G*bits/32) u32,
     zero (M*D/G, 1) f32, rng (M*D/G, 1) f32)`` — the stash triplet is
     bit-identical to ``quant_pack_call`` / the jnp reference on the same
-    ``x``.
+    ``x``.  Any ``M`` and ``N``: ``tm`` is rounded to whole blocks, and
+    ragged rows/columns are zero-padded to whole tiles (the padded rows
+    form fake blocks that are sliced off, never mixed into real ones).
     """
     m, d = x2d.shape
     n = w.shape[1]
+    assert (m * d) % group_size == 0, (x2d.shape, group_size)
+    tm = _whole_block_rows(tm, d, group_size)
+    xp = pad_to_multiple(x2d, tm)
+    wp = pad_to_multiple(w, tn, axis=1)
     seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
-    call = _build_matmul_quant(m, d, n, bits, group_size,
-                               levels, tm, tn, interpret)
-    return call(seed_arr, x2d, w)
+    call = _build_matmul_quant(xp.shape[0], d, wp.shape[1], bits,
+                               group_size, levels, tm, tn, interpret)
+    y, packed, zero, rng = call(seed_arr, xp, wp)
+    nb = m * d // group_size
+    return y[:m, :n], packed[:nb], zero[:nb], rng[:nb]
 
 
 def _dequant_matmul_kernel(packed_ref, zero_ref, rng_ref, g_ref, dw_ref,
@@ -196,26 +217,33 @@ def dequant_matmul_call(packed, zero, rng, g2d, bits: int, group_size: int,
 
     ``packed`` (M*D/G, W) + (zero, rng) (M*D/G, 1) are the stash of an
     (M, D) activation; ``g2d`` is (M, N).  ``tile_rows`` tiles the row
-    contraction — ``None`` (default) runs it as ONE tile, whose single
-    dot keeps the per-element reduction identical to the unfused
-    ``x̂ᵀ @ g`` (the bit-parity configuration).  Smaller tiles (real-TPU
-    VMEM sizing via the autotuner) emit one ``(D, TN)`` partial per row
-    tile and combine them with :func:`_tree_sum`: bit-stable run-to-run
-    and across grid schedules, float-tolerance vs the single-tile order.
+    contraction — ``None`` runs it as ONE tile, whose single dot keeps
+    the per-element reduction identical to the unfused ``x̂ᵀ @ g`` (the
+    bit-parity configuration, for shapes whose whole operand fits VMEM).
+    Smaller tiles (the autotuner's pick once one tile exceeds VMEM) emit
+    one ``(D, TN)`` partial per row tile and combine them with
+    :func:`_tree_sum`: bit-stable run-to-run and across grid schedules,
+    float-tolerance vs the single-tile order.  Any ``M``: ragged rows are
+    padded with zero codes of zero range (which decode to exact zeros)
+    and zero gradient rows, so they add nothing to ``dw``.
     """
     m, n = g2d.shape
-    tile_rows = m if tile_rows is None else tile_rows
-    assert m % tile_rows == 0 and n % tn == 0, (m, n, tile_rows, tn)
-    assert (tile_rows * d) % group_size == 0, (tile_rows, d, group_size)
+    assert packed.shape[0] * group_size == m * d, (packed.shape, m, d)
+    tile_rows = _whole_block_rows(m if tile_rows is None else tile_rows,
+                                  d, group_size)
+    gp = pad_to_multiple(pad_to_multiple(g2d, tile_rows), tn, axis=1)
     bpt = tile_rows * d // group_size
-    k_tiles = m // tile_rows
+    packed, zero, rng = (pad_to_multiple(a, bpt)
+                         for a in (packed, zero, rng))
+    k_tiles = gp.shape[0] // tile_rows
+    n_pad = gp.shape[1]
     kern = functools.partial(_dequant_matmul_kernel, bits=bits,
                              group_size=group_size, rows=tile_rows, d=d,
                              levels=levels)
     wpb = group_size // (32 // bits)
     parts = pl.pallas_call(
         kern,
-        grid=(n // tn, k_tiles),
+        grid=(n_pad // tn, k_tiles),
         in_specs=[
             pl.BlockSpec((bpt, wpb), lambda j, k: (k, 0)),
             pl.BlockSpec((bpt, 1), lambda j, k: (k, 0)),
@@ -223,9 +251,7 @@ def dequant_matmul_call(packed, zero, rng, g2d, bits: int, group_size: int,
             pl.BlockSpec((tile_rows, tn), lambda j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((1, d, tn), lambda j, k: (k, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((k_tiles, d, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k_tiles, d, n_pad), jnp.float32),
         interpret=interpret,
-    )(packed, zero, rng, g2d)
-    if k_tiles == 1:
-        return parts[0]
-    return _tree_sum(parts)
+    )(packed, zero, rng, gp)
+    return _tree_sum(parts)[:, :n]

@@ -12,7 +12,6 @@ counter hash and the pack layout is shared (see quant_blockwise.py).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -59,22 +58,6 @@ def static_levels(levels):
     return tuple(float(l) for l in np.asarray(levels).reshape(-1))
 
 
-def _pad_rows(x, multiple: int):
-    """Zero-pad whole rows up to ``multiple``.
-
-    Rows are quantization *blocks*: padding only appends fake blocks whose
-    stats live entirely in the sliced-off region ``[n:]`` — it can never
-    touch a real block's (zero, range).  Within-block tail padding (which
-    CAN widen the last real block's envelope if done with zeros) is the
-    caller's job via replicate-padding, see ``core.backend.to_blocks``.
-    """
-    n = x.shape[0]
-    pad = (-n) % multiple
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, *x.shape[1:]), x.dtype)], 0)
-    return x, n
-
-
 def quantize_packed(x2d, bits: int, seed, levels=None, *, impl: str = "auto",
                     rows_per_tile: int = 8):
     """(n_blocks, G) -> (packed u32, zero (n,), rng (n,))."""
@@ -82,11 +65,10 @@ def quantize_packed(x2d, bits: int, seed, levels=None, *, impl: str = "auto",
     levels = static_levels(levels)
     if impl == "jnp":
         return refmod.quantize_packed(x2d, bits, seed, levels)
-    xp, n = _pad_rows(x2d, rows_per_tile)
     packed, zero, rng = qk.quant_pack_call(
-        xp, bits, seed, levels, rows_per_tile=rows_per_tile,
+        x2d, bits, seed, levels, rows_per_tile=rows_per_tile,
         interpret=(impl == "interp"))
-    return packed[:n], zero[:n, 0], rng[:n, 0]
+    return packed, zero[:, 0], rng[:, 0]
 
 
 def dequantize_packed(packed, zero, rng, bits: int, group_size: int,
@@ -97,24 +79,21 @@ def dequantize_packed(packed, zero, rng, bits: int, group_size: int,
     levels = static_levels(levels)
     if impl == "jnp":
         return refmod.dequantize_packed(packed, zero, rng, bits, group_size, levels)
-    p, n = _pad_rows(packed, rows_per_tile)
-    z, _ = _pad_rows(zero[:, None], rows_per_tile)
-    r, _ = _pad_rows(rng[:, None], rows_per_tile)
-    out = qk.dequant_unpack_call(p, z, r, bits, group_size, levels,
-                                 rows_per_tile=rows_per_tile,
-                                 interpret=(impl == "interp"))
-    return out[:n]
+    return qk.dequant_unpack_call(packed, zero[:, None], rng[:, None], bits,
+                                  group_size, levels,
+                                  rows_per_tile=rows_per_tile,
+                                  interpret=(impl == "interp"))
 
 
 # ------------------------------------------------------- fused matmul+quant
 def _fused_tiles(kind: str, m: int, d: int, n: int, bits: int,
                  group_size: int, tm, tn):
-    """Resolve tile sizes: explicit args win, else the autotune cache /
-    roofline default (lazy import keeps ops light for non-fused callers)."""
+    """Resolve tile sizes: explicit args win, else the autotuner's pick
+    for this device (lazy import keeps ops light for non-fused callers)."""
     from repro.kernels import autotune
 
     auto_tm, auto_tn = autotune.get_tiles(kind, m, d, n, bits, group_size,
-                                          jax.default_backend())
+                                          autotune.tile_device_kind())
     return (tm if tm is not None else auto_tm,
             tn if tn is not None else auto_tn)
 
@@ -145,15 +124,10 @@ def matmul_quantize_packed(x2d, w, bits: int, seed, levels=None, *,
             levels)
         return y, packed, zero, rng
     tm, tn = _fused_tiles("fwd", m, d, n, bits, group_size, tm, tn)
-    step = group_size // math.gcd(group_size, d)
-    tm = max(step, (tm // step) * step)
-    xp, _ = _pad_rows(x2d, tm)
-    wp, _ = _pad_cols(w, tn)
     y, packed, zero, rng = fk.matmul_quant_call(
-        xp, wp, bits, seed, levels, group_size=group_size, tm=tm, tn=tn,
+        x2d, w, bits, seed, levels, group_size=group_size, tm=tm, tn=tn,
         interpret=(impl == "interp"))
-    nb = m * d // group_size
-    return y[:m, :n], packed[:nb], zero[:nb, 0], rng[:nb, 0]
+    return y, packed, zero[:, 0], rng[:, 0]
 
 
 def dequant_matmul_packed(packed, zero, rng, g2d, bits: int,
@@ -163,9 +137,10 @@ def dequant_matmul_packed(packed, zero, rng, g2d, bits: int,
     """Fused backward: ``dw = dequant(packed)ᵀ @ g`` for an (M, d) stash.
 
     The kernel unpacks+dequantizes the stashed tile as the prologue of
-    the backward matmul.  With the default single row tile the result is
-    bit-identical (up to the sign of exact zeros) to the unfused
-    ``dequantize_packed`` → reshape → ``x̂ᵀ @ g``.
+    the backward matmul.  Where the autotuner runs a single row tile (the
+    whole operand fits VMEM) the result is bit-identical (up to the sign
+    of exact zeros) to the unfused ``dequantize_packed`` → reshape →
+    ``x̂ᵀ @ g``; larger operands are row-tiled and tree-reduced.
     """
     impl = _resolve(impl)
     levels = static_levels(levels)
@@ -177,47 +152,10 @@ def dequant_matmul_packed(packed, zero, rng, g2d, bits: int,
         return x_hat.reshape(m, d).T @ g2d.astype(jnp.float32)
     tile_rows, tn = _fused_tiles("bwd", m, d, n, bits, group_size,
                                  tile_rows, tn)
-    step = group_size // math.gcd(group_size, d)
-    tile_rows = max(step, (tile_rows // step) * step)
-    gp, _ = _pad_rows(g2d, tile_rows)
-    gp, _ = _pad_cols(gp, tn)
-    pad_blocks = (gp.shape[0] - m) * d // group_size
-    if pad_blocks:
-        # zero-filled fake blocks decode to exact zeros -> zero dw terms
-        p = _pad_rows_to(packed, packed.shape[0] + pad_blocks)
-        z = _pad_rows_to(zero[:, None], zero.shape[0] + pad_blocks)
-        r = _pad_rows_to(rng[:, None], rng.shape[0] + pad_blocks)
-    else:
-        p, z, r = packed, zero[:, None], rng[:, None]
-    dw = fk.dequant_matmul_call(p, z, r, gp, bits, group_size, d, levels,
-                                tile_rows=tile_rows, tn=tn,
-                                interpret=(impl == "interp"))
-    return dw[:, :n]
-
-
-def _pad_cols(x, multiple: int):
-    n = x.shape[1]
-    pad = (-n) % multiple
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((x.shape[0], pad), x.dtype)], 1)
-    return x, n
-
-
-def _pad_rows_to(x, target: int):
-    """Zero-pad rows up to an exact row count (not a multiple)."""
-    pad = target - x.shape[0]
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, *x.shape[1:]), x.dtype)], 0)
-    return x
-
-
-def _pad2d(x, tm, tk):
-    m, d = x.shape
-    pm, pd = (-m) % tm, (-d) % tk
-    if pm or pd:
-        x = jnp.pad(x, ((0, pm), (0, pd)))
-    return x, m
+    return fk.dequant_matmul_call(packed, zero[:, None], rng[:, None], g2d,
+                                  bits, group_size, d, levels,
+                                  tile_rows=tile_rows, tn=tn,
+                                  interpret=(impl == "interp"))
 
 
 def rp_project(x2d, seed, d_out: int, *, impl: str = "auto",
@@ -227,10 +165,8 @@ def rp_project(x2d, seed, d_out: int, *, impl: str = "auto",
         return refmod.rp_project(x2d, seed, d_out)
     assert d_out % tn == 0 and x2d.shape[1] % tk == 0, \
         "rp_project pallas path needs D, d_out multiples of the tile"
-    xp, m = _pad2d(x2d, tm, tk)
-    out = rk.rp_project_call(xp, seed, d_out, tm=tm, tn=tn, tk=tk,
-                             interpret=(impl == "interp"))
-    return out[:m]
+    return rk.rp_project_call(x2d, seed, d_out, tm=tm, tn=tn, tk=tk,
+                              interpret=(impl == "interp"))
 
 
 def irp_project(x2d, seed, d_in: int, *, impl: str = "auto",
@@ -240,7 +176,5 @@ def irp_project(x2d, seed, d_in: int, *, impl: str = "auto",
         return refmod.irp_project(x2d, seed, d_in)
     assert d_in % tn == 0 and x2d.shape[1] % tk == 0, \
         "irp_project pallas path needs r, D multiples of the tile"
-    xp, m = _pad2d(x2d, tm, tk)
-    out = rk.irp_project_call(xp, seed, d_in, tm=tm, tn=tn, tk=tk,
-                              interpret=(impl == "interp"))
-    return out[:m]
+    return rk.irp_project_call(x2d, seed, d_in, tm=tm, tn=tn, tk=tk,
+                               interpret=(impl == "interp"))

@@ -29,12 +29,16 @@ from repro.core.quant import EPS as _EPS  # single shared clamp constant
 
 
 def _sr_codes(h, u, bits: int, levels):
-    """Stochastic-round normalized h in [0,B] to level indices (uint32)."""
-    B = 2**bits - 1
+    """Stochastic-round normalized h in [0,B] to level indices (uint32).
+
+    Float <-> integer conversions go through int32: Mosaic has no direct
+    float32 <-> uint32 cast, and codes (<= 255) are exact in int32.
+    """
     if levels is None:
         lo = jnp.floor(h)
         p_up = h - lo
-        return lo.astype(jnp.uint32) + (u < p_up).astype(jnp.uint32)
+        return (lo.astype(jnp.int32).astype(jnp.uint32)
+                + (u < p_up).astype(jnp.uint32))
     # non-uniform (VM) levels: unrolled bin search over a static table
     idx = jnp.zeros(h.shape, jnp.uint32)
     for lv in levels[1:-1]:
@@ -52,7 +56,7 @@ def _sr_codes(h, u, bits: int, levels):
 def _levels_value(codes, bits: int, levels):
     """Map level indices back to level values (f32)."""
     if levels is None:
-        return codes.astype(jnp.float32)
+        return codes.astype(jnp.int32).astype(jnp.float32)
     out = jnp.zeros(codes.shape, jnp.float32)
     for i, lv in enumerate(levels):
         out = jnp.where(codes == jnp.uint32(i), jnp.float32(lv), out)
@@ -95,19 +99,40 @@ def _dequant_unpack_kernel(packed_ref, zero_ref, rng_ref, out_ref,
     out_ref[...] = vals * (rng_ref[...] / B) + zero_ref[...]
 
 
+def pad_to_multiple(x, multiple: int, axis: int = 0):
+    """Zero-pad ``x`` along ``axis`` up to a multiple of ``multiple``.
+
+    The kernel wrappers below (and in ``fused_matmul``/``rp_matmul``) pad
+    ragged operands to whole tiles themselves, so any row count reaches
+    the kernel: real graphs are rarely a multiple of 8, 128 or 512 rows.
+    Padded rows are fake blocks or zero rows whose outputs are sliced off.
+    """
+    pad = (-x.shape[axis]) % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
 def quant_pack_call(x2d, bits: int, seed, levels=None, *,
                     rows_per_tile: int = 8, interpret: bool = False):
-    """x2d (n_blocks, G) -> (packed, zero(n,1), rng(n,1)); n_blocks % rows == 0."""
+    """x2d (n_blocks, G) -> (packed (n, W), zero (n, 1), rng (n, 1)).
+
+    Any ``n_blocks``: the tail tile is zero-padded with fake blocks whose
+    outputs are dropped, so real block stats are never touched.
+    """
     n, g = x2d.shape
     vpw = 32 // bits
     assert g % vpw == 0, f"group_size {g} must be a multiple of {vpw}"
-    assert n % rows_per_tile == 0
+    xp = pad_to_multiple(x2d, rows_per_tile)
+    n_pad = xp.shape[0]
     w = g // vpw
     seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
     kern = functools.partial(_quant_pack_kernel, bits=bits, group_size=g,
                              rows=rows_per_tile, levels=levels)
-    grid = (n // rows_per_tile,)
-    return pl.pallas_call(
+    grid = (n_pad // rows_per_tile,)
+    packed, zero, rng = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -120,25 +145,28 @@ def quant_pack_call(x2d, bits: int, seed, levels=None, *,
             pl.BlockSpec((rows_per_tile, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, w), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, w), jnp.uint32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(seed_arr, x2d)
+    )(seed_arr, xp)
+    return packed[:n], zero[:n], rng[:n]
 
 
 def dequant_unpack_call(packed, zero, rng, bits: int, group_size: int,
                         levels=None, *, rows_per_tile: int = 8,
                         interpret: bool = False):
-    """(packed, zero(n,1), rng(n,1)) -> x_hat (n_blocks, G) f32."""
+    """(packed, zero(n,1), rng(n,1)) -> x_hat (n_blocks, G) f32; any n."""
     n, w = packed.shape
     assert w * (32 // bits) == group_size
-    assert n % rows_per_tile == 0
+    packed, zero, rng = (pad_to_multiple(a, rows_per_tile)
+                         for a in (packed, zero, rng))
+    n_pad = packed.shape[0]
     kern = functools.partial(_dequant_unpack_kernel, bits=bits,
                              group_size=group_size, levels=levels)
-    grid = (n // rows_per_tile,)
-    return pl.pallas_call(
+    grid = (n_pad // rows_per_tile,)
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -147,6 +175,7 @@ def dequant_unpack_call(packed, zero, rng, bits: int, group_size: int,
             pl.BlockSpec((rows_per_tile, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((rows_per_tile, group_size), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, group_size), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_pad, group_size), jnp.float32),
         interpret=interpret,
     )(packed, zero, rng)
+    return out[:n]

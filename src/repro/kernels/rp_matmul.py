@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.prng import rademacher_from_counter
+from repro.kernels.quant_blockwise import pad_to_multiple
 
 
 def _rp_kernel(seed_ref, x_ref, o_ref, *, tk: int, tn: int,
@@ -41,28 +42,30 @@ def _rp_kernel(seed_ref, x_ref, o_ref, *, tk: int, tn: int,
         # tile of R: element (d, p) = sign(hash(d * r_dim + p))
         counter = rid * jnp.uint32(r_dim) + cid
     signs = rademacher_from_counter(seed_ref[0, 0], counter)
-    r = signs.astype(jnp.float32) * jnp.float32(1.0 / (r_dim ** 0.5))
+    r = signs * jnp.float32(1.0 / (r_dim ** 0.5))
     o_ref[...] += jnp.dot(x, r, preferred_element_type=jnp.float32)
 
 
 def _call(x2d, seed, n_out: int, r_dim: int, transpose: bool,
           tm: int, tn: int, tk: int, interpret: bool):
     m, d = x2d.shape
-    assert m % tm == 0 and d % tk == 0 and n_out % tn == 0, (m, d, n_out)
+    assert d % tk == 0 and n_out % tn == 0, (d, n_out)
+    # ragged rows are zero rows of the padded operand, sliced off below
+    x2d = pad_to_multiple(x2d, tm)
     seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
     kern = functools.partial(_rp_kernel, tk=tk, tn=tn, r_dim=r_dim,
                              transpose=transpose)
     return pl.pallas_call(
         kern,
-        grid=(m // tm, n_out // tn, d // tk),
+        grid=(x2d.shape[0] // tm, n_out // tn, d // tk),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
             pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n_out), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((x2d.shape[0], n_out), jnp.float32),
         interpret=interpret,
-    )(seed_arr, x2d)
+    )(seed_arr, x2d)[:m]
 
 
 def rp_project_call(x2d, seed, d_out: int, *, tm=128, tn=128, tk=128,

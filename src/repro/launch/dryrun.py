@@ -1,15 +1,20 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape) on the
 single-pod (16,16) and multi-pod (2,16,16) production meshes, record
 memory_analysis / cost_analysis / collective bytes (parsed from optimized
 HLO) into results/dryrun/*.json.
 
+A CPU-only tool: importing it forces 512 virtual host devices (before JAX
+initializes) and the production meshes are laid out over them.  Run it
+with ``JAX_PLATFORMS=cpu``; it spawns a subprocess per cell, so it stays
+off the chip path, where one process holds the chip.
+
 Usage:
   python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all            # subprocess per cell
 """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import re

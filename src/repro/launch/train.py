@@ -27,6 +27,7 @@ from repro.obs.trace import stopwatch
 from repro.parallel import annotate
 from repro.parallel.sharding import batch_pspecs, param_pspecs, to_named
 from repro.runtime import StragglerMonitor, TrainRunner
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _graph_main(args):
@@ -296,4 +297,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

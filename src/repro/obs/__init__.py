@@ -9,7 +9,7 @@ bit-identical to obs-off — gated in ``tests/test_obs.py``):
   :func:`~repro.obs.trace.stopwatch` timing idiom;
 * :mod:`~repro.obs.metrics` — counters / gauges / windowed histograms
   with shared null singletons when disabled (arena occupancy, pager
-  overlap, halo bytes, autotune cache hits, recompile counts);
+  overlap, halo bytes, recompile counts);
 * :mod:`~repro.obs.quantstats` — the opt-in per-layer in-graph stats
   channel: measured SR dequantization variance, range moments and
   saturation rate per layer, shipped through one batched

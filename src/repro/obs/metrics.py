@@ -3,12 +3,11 @@ disabled.
 
 A disabled :class:`MetricsRegistry` hands out shared null singletons
 whose methods are empty — producers instrument unconditionally
-(``registry.counter("autotune/cache_hit").inc()``) and pay one no-op
+(``registry.counter("engine/forward_builds").inc()``) and pay one no-op
 method call when observability is off.  Like the tracer, the module
 keeps one *active* registry (:func:`set_metrics` / :func:`get_metrics`,
 default disabled) for producers that have no session handy (the
-autotuner's trace-time cache reads, the forward builder's recompile
-counter).
+forward builder's recompile counter).
 
 :class:`Histogram` keeps cumulative moments **and** a bounded window of
 the most recent observations — the fix for the pager's ``overlap_frac``,

@@ -3,8 +3,8 @@
 
 Bundles the tracer, the metrics registry, and the quant-health monitor;
 ``activate()`` installs the tracer/registry as the process-wide actives
-(so producers without a session handle — the autotuner, the forward
-builder, benchmark stopwatches — land in the same sinks) and restores
+(so producers without a session handle — the forward builder,
+benchmark stopwatches — land in the same sinks) and restores
 the previous ones on exit.  The shared :data:`NULL_SESSION` serves every
 disabled run: all of its span/metric methods are no-ops, so the engine
 instruments unconditionally.

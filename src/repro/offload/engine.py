@@ -93,11 +93,13 @@ def measure_live_bytes() -> int:
 
 def device_memory_stats() -> dict | None:
     """Raw device memory stats (``peak_bytes_in_use`` etc.) where the
-    backend exposes them (TPU/GPU); None on CPU."""
-    try:
-        return jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
+    backend exposes them (TPU/GPU); None on CPU.  A TPU without stats is
+    an error: the device-peak column would silently read as unmeasured."""
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats is None and dev.platform == "tpu":
+        raise RuntimeError(f"{dev.device_kind} reports no memory stats")
+    return stats
 
 
 def device_resident_stash_bytes(plan: ar.StashPlan, policy: str) -> int:

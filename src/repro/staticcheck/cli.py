@@ -31,13 +31,9 @@ PASSES = ("seed-lint", "plan-verify", "kernel-contracts", "jaxpr-audit")
 
 def tree_digest(root: pathlib.Path = REPO_ROOT) -> str:
     """Digest of everything the traced plan matrix depends on: the whole
-    ``src/repro`` tree plus the persisted autotune tiles."""
+    ``src/repro`` tree."""
     h = hashlib.sha256()
-    paths = sorted((root / "src" / "repro").rglob("*.py"))
-    tiles = root / "results" / "autotune" / "fused_tiles.json"
-    if tiles.exists():
-        paths.append(tiles)
-    for p in paths:
+    for p in sorted((root / "src" / "repro").rglob("*.py")):
         h.update(str(p.relative_to(root)).encode())
         h.update(p.read_bytes())
     return h.hexdigest()

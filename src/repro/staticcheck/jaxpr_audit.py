@@ -40,6 +40,7 @@ import dataclasses
 from types import SimpleNamespace
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.offload.arena import StashPlan
@@ -134,7 +135,7 @@ def audit_forward(fwd, example_args, splan: StashPlan, mechanism: str,
     remaining = list(expected)
     ledger = 0
     for leaf in jx.outvars[n_primal:]:
-        if isinstance(leaf, jax.core.Literal) or leaf in passthrough:
+        if isinstance(leaf, jax_core.Literal) or leaf in passthrough:
             continue  # pass-through residual: no new HBM
         aval = leaf.aval
         sig = (str(jnp.dtype(aval.dtype)), tuple(aval.shape))
@@ -169,7 +170,7 @@ def audit_forward(fwd, example_args, splan: StashPlan, mechanism: str,
         for eqn in _iter_eqns(jx):
             if eqn.primitive.name == "pure_callback":
                 ledger += sum(_nbytes(v.aval) for v in eqn.invars[2:]
-                              if not isinstance(v, jax.core.Literal))
+                              if not isinstance(v, jax_core.Literal))
     return findings, ledger
 
 

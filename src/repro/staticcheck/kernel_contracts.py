@@ -1,19 +1,19 @@
 """Declarative pre/post-conditions for the compression kernels, evaluated
-against every persisted autotune entry.
+against the tiles the autotuner picks.
 
 Each :class:`Contract` states one invariant the Pallas kernels assume and
-checks it over a parsed ``results/autotune/fused_tiles.json`` entry
-(``{kind}/{m}x{d}x{n}/b{bits}/g{group}/{backend}`` → ``(t0, t1)`` tiles):
+checks it over one tile choice
+(``{kind}/{m}x{d}x{n}/b{bits}/g{group}/{device_kind}`` → ``(t0, t1)``),
+for every fused layer of the plan matrix and the full-width arxiv layers:
 
 * ``fused_matmul`` forward — the row tile owns whole quantization blocks
   (``tm % row_tile_step(d, G) == 0``, the same legality
   :func:`repro.kernels.autotune.fwd_candidates` enumerates), tiles stay
   inside the (step-padded) operand, and the tile working set fits the
   per-core VMEM budget;
-* ``fused_matmul`` backward — the row tile divides ``m`` exactly (the
-  fixed-order tree reduction needs equal splits) and owns whole blocks;
-  the single-tile ``tile_rows == m`` configuration is VMEM-exempt by
-  design (it is the bit-exact fallback, never auto-picked over budget);
+* ``fused_matmul`` backward — the row tile owns whole blocks and fits
+  the VMEM budget, the single ``tile_rows == m`` tile included (the
+  kernel wrapper pads a ragged last tile, so it need not divide ``m``);
 * ``quant_blockwise`` — the base kernel preconditions (bits divides 32,
   the pack width divides the group, VM level tables fit the unrolled
   compare/select chain), via the one predicate the dispatch layer routes
@@ -22,32 +22,33 @@ checks it over a parsed ``results/autotune/fused_tiles.json`` entry
   (``compress`` asserts this at trace time; off-grid tile shapes are an
   allowed jnp fallback, not a violation).
 
-A cache entry violating a contract means the autotuner persisted tiles a
-kernel launch would miscompute or OOM on — exactly the class of bug that
-only surfaces on real TPU hardware otherwise.
+A pick violating a contract means the autotuner chose tiles a kernel
+launch would miscompute or OOM on — exactly the class of bug that only
+surfaces on real TPU hardware otherwise.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import pathlib
 import re
 from typing import Callable
 
 from repro.core import backend
-from repro.kernels.autotune import VMEM_BUDGET, cache_path, row_tile_step
+from repro.kernels.autotune import (VMEM_BUDGET, bwd_vmem_bytes,
+                                    fwd_vmem_bytes, get_tiles)
+from repro.kernels.fused_matmul import row_tile_step
+from repro.kernels.peaks import TARGET_KIND
 from repro.staticcheck.findings import Finding
 
 PASS = "kernel-contracts"
 
 _KEY_RE = re.compile(
     r"^(?P<kind>fwd|bwd)/(?P<m>\d+)x(?P<d>\d+)x(?P<n>\d+)"
-    r"/b(?P<bits>\d+)/g(?P<group>\d+)/(?P<backend>[\w-]+)$")
+    r"/b(?P<bits>\d+)/g(?P<group>\d+)/(?P<device>[\w -]+)$")
 
 
 @dataclasses.dataclass(frozen=True)
 class Entry:
-    """One parsed autotune-cache row."""
+    """One parsed tile choice."""
 
     key: str
     kind: str
@@ -56,7 +57,7 @@ class Entry:
     n: int
     bits: int
     group_size: int
-    backend: str
+    device: str
     t0: int  # tm (fwd) | tile_rows (bwd)
     t1: int  # tn
 
@@ -70,11 +71,11 @@ class Contract:
 
 
 def _fwd_vmem(e: Entry) -> int:
-    return 4 * (e.t0 * e.d + e.d * e.t1 + e.t0 * e.t1 + e.t0 * e.d // 8)
+    return fwd_vmem_bytes(e.t0, e.d, e.t1, e.bits, e.group_size)
 
 
 def _bwd_vmem(e: Entry) -> int:
-    return 4 * (e.t0 * e.d + e.t0 * e.t1 + e.d * e.t1 + e.t0 * e.d // 8)
+    return bwd_vmem_bytes(e.t0, e.d, e.t1, e.bits, e.group_size)
 
 
 def _tile_positive(e: Entry) -> str | None:
@@ -122,9 +123,6 @@ def _bwd_block_alignment(e: Entry) -> str | None:
     if e.t0 % step:
         return (f"row tile tile_rows={e.t0} does not own whole "
                 f"quantization blocks: need a multiple of step={step}")
-    if e.m % e.t0:
-        return (f"tile_rows={e.t0} does not divide m={e.m}: the M-split "
-                "tree reduction needs equal row splits")
     return None
 
 
@@ -137,11 +135,9 @@ def _bwd_index_bounds(e: Entry) -> str | None:
 
 
 def _bwd_vmem_budget(e: Entry) -> str | None:
-    if e.t0 == e.m:
-        return None  # the bit-exact single-tile config is budget-exempt
     vmem = _bwd_vmem(e)
     if vmem > VMEM_BUDGET:
-        return (f"row-split tile ({e.t0}, {e.t1}) needs {vmem} bytes of "
+        return (f"row tile ({e.t0}, {e.t1}) needs {vmem} bytes of "
                 f"VMEM over the {VMEM_BUDGET}-byte per-core budget")
     return None
 
@@ -159,12 +155,11 @@ CONTRACTS: tuple[Contract, ...] = (
     Contract("vmem-budget", "fwd tile working set fits VMEM",
              "fwd", _fwd_vmem_budget),
     Contract("tile-block-alignment",
-             "bwd row tile owns whole blocks and divides m exactly",
+             "bwd row tile owns whole quantization blocks",
              "bwd", _bwd_block_alignment),
     Contract("tile-bounds", "bwd tiles stay inside the operand",
              "bwd", _bwd_index_bounds),
-    Contract("vmem-budget",
-             "bwd row-split tile fits VMEM (tile_rows == m exempt)",
+    Contract("vmem-budget", "bwd row tile fits VMEM",
              "bwd", _bwd_vmem_budget),
 )
 
@@ -176,16 +171,16 @@ def parse_entry(key: str, tiles) -> Entry | None:
         return None
     return Entry(key=key, kind=m["kind"], m=int(m["m"]), d=int(m["d"]),
                  n=int(m["n"]), bits=int(m["bits"]),
-                 group_size=int(m["group"]), backend=m["backend"],
+                 group_size=int(m["group"]), device=m["device"],
                  t0=int(tiles[0]), t1=int(tiles[1]))
 
 
 def check_entry(key: str, tiles) -> list[Finding]:
     e = parse_entry(key, tiles)
     if e is None:
-        return [Finding(PASS, "cache-key", key,
-                        f"unparseable autotune entry (tiles={tiles!r}); "
-                        "expected kind/MxDxN/bBITS/gGROUP/backend -> "
+        return [Finding(PASS, "tile-key", key,
+                        f"unparseable tile choice (tiles={tiles!r}); "
+                        "expected kind/MxDxN/bBITS/gGROUP/device_kind -> "
                         "[t0, t1]")]
     out = []
     for c in CONTRACTS:
@@ -197,19 +192,19 @@ def check_entry(key: str, tiles) -> list[Finding]:
     return out
 
 
-def check_autotune_cache(path: pathlib.Path | None = None) -> list[Finding]:
-    """Evaluate every contract against every persisted cache entry."""
-    p = pathlib.Path(path) if path is not None else cache_path()
-    if not p.exists():
-        return []
-    try:
-        cache = json.loads(p.read_text())
-    except (ValueError, OSError) as e:
-        return [Finding(PASS, "cache-key", str(p),
-                        f"autotune cache is not valid JSON: {e}")]
+def tile_key(kind: str, m: int, d: int, n: int, bits: int,
+             group_size: int, device_kind: str = TARGET_KIND) -> str:
+    return f"{kind}/{m}x{d}x{n}/b{bits}/g{group_size}/{device_kind}"
+
+
+def check_tile_choices(shapes) -> list[Finding]:
+    """Every contract over the autotuner's fwd and bwd picks for each
+    ``(m, d, n, bits, group_size)`` fused matmul shape."""
     out = []
-    for key in sorted(cache):
-        out.extend(check_entry(key, cache[key]))
+    for shape in sorted(set(shapes)):
+        for kind in ("fwd", "bwd"):
+            out.extend(check_entry(tile_key(kind, *shape),
+                                   get_tiles(kind, *shape, TARGET_KIND)))
     return out
 
 
@@ -251,5 +246,35 @@ def check_matrix_configs() -> list[Finding]:
     return out
 
 
-def run(path: pathlib.Path | None = None) -> list[Finding]:
-    return check_autotune_cache(path) + check_matrix_configs()
+def matrix_fused_shapes() -> list[tuple[int, int, int, int, int]]:
+    """``(m, d, n, bits, group_size)`` of every fused matmul the plan
+    matrix's ``fused='on'`` cells launch."""
+    from repro.graph.models import _dims
+    from repro.staticcheck.matrix import audit_matrix
+
+    out = []
+    for case in audit_matrix():
+        if case.plan.kernel.fused != "on":
+            continue
+        dims = _dims(case.cfg, case.in_dim)
+        for d_in, d_out, comp in zip(dims[:-1], dims[1:],
+                                     case.cfg.layer_compression()):
+            lin_in = d_in * (2 if case.cfg.arch == "sage" else 1)
+            if comp is not None and backend.supports_fused(
+                    (case.live_nodes, lin_in), comp.bits, comp.group_size):
+                out.append((case.live_nodes, lin_in, d_out, comp.bits,
+                            comp.group_size))
+    return out
+
+
+#: The full-width fused layers of GraphSAGE on arxiv (169,343 nodes, 128
+#: features, hidden (256, 256), 40 classes) at INT2, G=128 — the shapes
+#: the chip smoke trains.
+ARXIV_FUSED_SHAPES = tuple((169343, d, n, 2, 128)
+                           for d, n in ((256, 256), (512, 256), (512, 40)))
+
+
+def run() -> list[Finding]:
+    return (check_tile_choices([*matrix_fused_shapes(),
+                                *ARXIV_FUSED_SHAPES])
+            + check_matrix_configs())

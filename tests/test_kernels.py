@@ -91,11 +91,13 @@ def _np_tree(parts):
     return parts[0]
 
 
-@pytest.mark.parametrize("tile_rows,m", [(128, 384), (128, 256), (64, 320)])
+@pytest.mark.parametrize("tile_rows,m", [(128, 384), (128, 256), (64, 320),
+                                         (128, 300)])
 def test_fused_bwd_tiled_is_fixed_order_tree(tile_rows, m):
     """Row-tiled fused backward == the fixed-order pairwise tree over
-    per-tile ``x̂ᵀ@g`` partials, exactly — including odd tile counts —
-    and is bit-stable across repeated runs."""
+    per-tile ``x̂ᵀ@g`` partials, exactly — including odd tile counts and
+    a ragged last tile, which the kernel pads with zero rows — and is
+    bit-stable across repeated runs."""
     d, n, bits, g = 32, 128, 2, 32
     x = jax.random.normal(jax.random.PRNGKey(0), (m, d), jnp.float32)
     gy = jax.random.normal(jax.random.PRNGKey(2), (m, n), jnp.float32)
@@ -103,11 +105,13 @@ def test_fused_bwd_tiled_is_fixed_order_tree(tile_rows, m):
                                   impl="jnp")
     x_hat = np.asarray(ops.dequantize_packed(
         p, z, r, bits, g, None, impl="interp")).reshape(m, d)
-    k_tiles = m // tile_rows
+    k_tiles = -(-m // tile_rows)
+    pad = ((0, k_tiles * tile_rows - m), (0, 0))
+    x_pad, gy_pad = np.pad(x_hat, pad), np.pad(np.asarray(gy), pad)
     parts = np.stack([
-        np.asarray(jnp.dot(jnp.asarray(x_hat[k * tile_rows:
+        np.asarray(jnp.dot(jnp.asarray(x_pad[k * tile_rows:
                                              (k + 1) * tile_rows]).T,
-                           gy[k * tile_rows:(k + 1) * tile_rows],
+                           gy_pad[k * tile_rows:(k + 1) * tile_rows],
                            preferred_element_type=jnp.float32))
         for k in range(k_tiles)])
     dw = ops.dequant_matmul_packed(p, z, r, gy, bits, g, d, None,
@@ -132,3 +136,33 @@ def test_tree_sum_orders():
                                   jnp.float32)
         np.testing.assert_array_equal(np.asarray(_tree_sum(parts)),
                                       _np_tree(np.asarray(parts)))
+
+
+def test_peak_table_rejects_unknown_device_kind():
+    """Tiles are ranked against one chip's published rates; a kind missing
+    from the table is an error, never a silent default."""
+    from repro.kernels import autotune, peaks
+
+    assert peaks.chip_peaks("TPU v5 lite").hbm_bytes_per_s == 819e9
+    with pytest.raises(ValueError, match="TPU v99"):
+        peaks.chip_peaks("TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        autotune.get_tiles("bwd", 1024, 256, 256, 2, 128, "TPU v99")
+
+
+@pytest.mark.parametrize("d,n,g", [(256, 256, 128), (512, 256, 128),
+                                   (512, 40, 128), (512, 256, 256)])
+def test_fused_tiles_fit_vmem_at_arxiv_rows(d, n, g):
+    """At 169,343 rows one backward tile would need far more VMEM than a
+    core has: the pick is a fixed row tile under the budget, and the
+    forward pick fits too."""
+    from repro.kernels import autotune
+
+    m = 169343
+    tr, tn = autotune.get_tiles("bwd", m, d, n, 2, g, "TPU v5 lite")
+    assert tr < m and tr % 8 == 0
+    assert (autotune.bwd_vmem_bytes(tr, d, tn, 2, g)
+            <= autotune.VMEM_BUDGET)
+    tm, tn = autotune.get_tiles("fwd", m, d, n, 2, g, "TPU v5 lite")
+    assert (autotune.fwd_vmem_bytes(tm, d, tn, 2, g)
+            <= autotune.VMEM_BUDGET)

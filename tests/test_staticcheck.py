@@ -1,7 +1,6 @@
 """repro.staticcheck: crafted violations each yield exactly their finding,
 and the real repo comes up clean."""
 import dataclasses
-import json
 
 import jax.numpy as jnp
 import pytest
@@ -142,20 +141,20 @@ def test_mesh_cross_policy_rules():
 # -------------------------------------------------------- kernel-contracts
 
 
-def test_oversized_autotune_tile_is_exactly_detected(tmp_path):
-    cache = tmp_path / "fused_tiles.json"
-    cache.write_text(json.dumps(
-        {"fwd/4096x1024x4096/b2/g64/cpu": [2048, 2048]}))
-    got = kernel_contracts.check_autotune_cache(cache)
+def test_oversized_autotune_tile_is_exactly_detected():
+    got = kernel_contracts.check_entry(
+        "fwd/4096x1024x4096/b2/g64/TPU v5 lite", [2048, 2048])
     assert [f.rule for f in got] == ["vmem-budget"]
     assert "VMEM" in got[0].message
+    # a single backward tile over the budget is no longer exempt
+    got = kernel_contracts.check_entry(
+        "bwd/169343x512x256/b2/g128/TPU v5 lite", [169343, 128])
+    assert [f.rule for f in got] == ["vmem-budget"]
 
 
-def test_malformed_cache_key_is_detected(tmp_path):
-    cache = tmp_path / "fused_tiles.json"
-    cache.write_text(json.dumps({"fwd/banana": [128, 128]}))
-    got = kernel_contracts.check_autotune_cache(cache)
-    assert [f.rule for f in got] == ["cache-key"]
+def test_malformed_cache_key_is_detected():
+    got = kernel_contracts.check_entry("fwd/banana", [128, 128])
+    assert [f.rule for f in got] == ["tile-key"]
 
 
 def test_real_autotune_cache_is_contract_clean():
