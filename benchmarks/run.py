@@ -6,7 +6,7 @@ suite drops ``BENCH_gnn_batched.json`` (mini-batch vs full-graph engine).
 
 Set ``REPRO_TRACE_OUT=<base>`` to trace the whole sweep: one obs span per
 suite (plus every ``stopwatch``-timed region inside the harnesses),
-exported to ``<base>.jsonl`` and ``<base>.trace.json`` (Perfetto)."""
+exported to ``<base>.trace.json`` (Perfetto)."""
 from __future__ import annotations
 
 import os
@@ -63,12 +63,9 @@ def main() -> None:
             from repro.obs.trace import set_tracer
 
             set_tracer(prev)
-            base = trace_out[:-6] if trace_out.endswith(".jsonl") else \
-                trace_out[:-5] if trace_out.endswith(".json") else trace_out
-            tracer.export_jsonl(base + ".jsonl")
+            base = trace_out.removesuffix(".json").removesuffix(".trace")
             tracer.export_chrome(base + ".trace.json")
-            print(f"# obs trace: {base}.jsonl + {base}.trace.json",
-                  file=sys.stderr)
+            print(f"# obs trace: {base}.trace.json", file=sys.stderr)
     if failures:
         sys.exit(1)
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import backend
 from repro.core.variance import optimize_levels
+from repro.obs import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,13 +142,14 @@ def compress(x: jnp.ndarray, cfg: CompressionConfig, seed,
     rp_seed = seed ^ jnp.uint32(0xA5A5_A5A5)
     requested = impl if impl is not None else cfg.impl
     levels = cfg.levels()
-    if cfg.rp_ratio > 1:
-        x = backend.rp(x.astype(jnp.float32), rp_seed,
-                       x.shape[-1] // cfg.rp_ratio, impl=requested)
     impl_q = backend.route_quant(requested, cfg.bits, cfg.group_size, levels)
-    blocks, _ = backend.to_blocks(x.astype(jnp.float32), cfg.group_size)
-    packed, zero, rng = backend.quantize_blocks(
-        blocks, cfg.bits, seed, levels, impl=impl_q)
+    with jax.named_scope(scopes.ACT_COMPRESS):
+        if cfg.rp_ratio > 1:
+            x = backend.rp(x.astype(jnp.float32), rp_seed,
+                           x.shape[-1] // cfg.rp_ratio, impl=requested)
+        blocks, _ = backend.to_blocks(x.astype(jnp.float32), cfg.group_size)
+        packed, zero, rng = backend.quantize_blocks(
+            blocks, cfg.bits, seed, levels, impl=impl_q)
     return CompressedTensor(packed, zero, rng, rp_seed,
                             shape=orig_shape, dtype=orig_dtype, cfg=cfg,
                             impl=impl_q)
@@ -174,9 +176,12 @@ def compress_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: CompressionConfig,
     if concrete is None:
         ct = compress(x, cfg, seed, impl=impl)
         return x.astype(jnp.float32) @ w.astype(jnp.float32), ct
-    y, packed, zero, rng = backend.matmul_quantize(
-        x.astype(jnp.float32), w.astype(jnp.float32), cfg.bits, seed,
-        levels, impl=concrete, group_size=cfg.group_size)
+    # the fused kernel is the layer's matmul and its compression in one:
+    # all of it is the compression route's
+    with jax.named_scope(scopes.ACT_COMPRESS):
+        y, packed, zero, rng = backend.matmul_quantize(
+            x.astype(jnp.float32), w.astype(jnp.float32), cfg.bits, seed,
+            levels, impl=concrete, group_size=cfg.group_size)
     ct = CompressedTensor(packed, zero, rng,
                           seed ^ jnp.uint32(0xA5A5_A5A5),
                           shape=tuple(x.shape), dtype=x.dtype, cfg=cfg,
@@ -206,9 +211,11 @@ def decompress_matmul(ct: CompressedTensor, g2d: jnp.ndarray,
         x_hat = decompress(ct, impl=impl)
         return (x_hat.reshape(-1, d).astype(jnp.float32).T
                 @ g2d.astype(jnp.float32))
-    return backend.dequant_matmul(ct.packed, ct.zero, ct.rng,
-                                  g2d.astype(jnp.float32), cfg.bits,
-                                  cfg.group_size, d, levels, impl=concrete)
+    with jax.named_scope(scopes.ACT_DECOMPRESS):
+        return backend.dequant_matmul(ct.packed, ct.zero, ct.rng,
+                                      g2d.astype(jnp.float32), cfg.bits,
+                                      cfg.group_size, d, levels,
+                                      impl=concrete)
 
 
 def decompress(ct: CompressedTensor, impl: str | None = None) -> jnp.ndarray:
@@ -223,10 +230,11 @@ def decompress(ct: CompressedTensor, impl: str | None = None) -> jnp.ndarray:
     requested = impl if impl is not None else backend.available_impl(ct.impl)
     proj_shape = _proj_shape(ct.shape, cfg.rp_ratio)
     levels = cfg.levels()
-    blocks = backend.dequantize_blocks(
-        ct.packed, ct.zero, ct.rng, cfg.bits, cfg.group_size, levels,
-        impl=requested)
-    x = backend.from_blocks(blocks, proj_shape)
-    if cfg.rp_ratio > 1:
-        x = backend.irp(x, ct.rp_seed, ct.shape[-1], impl=requested)
-    return x.astype(ct.dtype)
+    with jax.named_scope(scopes.ACT_DECOMPRESS):
+        blocks = backend.dequantize_blocks(
+            ct.packed, ct.zero, ct.rng, cfg.bits, cfg.group_size, levels,
+            impl=requested)
+        x = backend.from_blocks(blocks, proj_shape)
+        if cfg.rp_ratio > 1:
+            x = backend.irp(x, ct.rp_seed, ct.shape[-1], impl=requested)
+        return x.astype(ct.dtype)

@@ -44,6 +44,7 @@ from repro.core.act_compress import zero_ct
 from repro.core.compressor import compress_matmul, decompress_matmul
 from repro.engine import seeds
 from repro.engine.plan import StashPolicy
+from repro.obs import scopes
 from repro.offload import engine as stash_engine
 from repro.offload.arena import StashPlan
 from repro.offload.gnn import plan_gnn_stashes  # noqa: F401  (re-export)
@@ -82,7 +83,9 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
     def layer_input(h, src, dst, mean_w, n):
         if not sage:
             return h
-        return jnp.concatenate([h, _spmm(h, src, dst, mean_w, n)], axis=1)
+        with jax.named_scope(scopes.AGG_FWD):
+            agg = _spmm(h, src, dst, mean_w, n)
+        return jnp.concatenate([h, agg], axis=1)
 
     @jax.custom_vjp
     def f(params, feats, src, dst, gcn_w, mean_w, seed, nm):
@@ -102,22 +105,31 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
             lseed = seeds.layer_seed(seed, li)
             x = layer_input(h, src, dst, mean_w, n)
             comp = per_layer[li]
+            with jax.named_scope(scopes.DENSE):
+                if comp is None:
+                    y = x @ p["w"]
+                else:
+                    # fused path: x is quantized+packed in the matmul
+                    # epilogue (one HBM read of x); routing falls back to
+                    # the unfused compress + x @ w spelling per layer when
+                    # declined
+                    y, ct = compress_matmul(x, p["w"], comp, lseed,
+                                            fused=fused)
+                z = y + p["b"]
             if comp is None:
                 writer.put_raw(li, x)
-                z = x @ p["w"] + p["b"]
             else:
-                # fused path: x is quantized+packed in the matmul epilogue
-                # (one HBM read of x); routing falls back to the unfused
-                # compress + x @ w spelling per layer when declined
-                y, ct = compress_matmul(x, p["w"], comp, lseed, fused=fused)
                 writer.put_ct(li, ct)
-                z = y + p["b"]
             if not sage:
-                z = _spmm(z, src, dst, gcn_w, n)
+                with jax.named_scope(scopes.AGG_FWD):
+                    z = _spmm(z, src, dst, gcn_w, n)
             if li < L - 1:
-                writer.put_mask(li, packmod.pack(
-                    (z > 0).astype(jnp.int32).reshape(1, -1), 1))
-                z = jnp.maximum(z, 0.0)
+                with jax.named_scope(scopes.RELU_MASK):
+                    mask = packmod.pack(
+                        (z > 0).astype(jnp.int32).reshape(1, -1), 1)
+                writer.put_mask(li, mask)
+                with jax.named_scope(scopes.DENSE):
+                    z = jnp.maximum(z, 0.0)
             h = z * nm[:, None]
         return h, (params, src, dst, gcn_w, mean_w, nm, writer.residual())
 
@@ -136,29 +148,35 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
             lp = plan.layers[li]
             g = gh * nm[:, None]
             if li < L - 1:
-                m = packmod.unpack(reader.get_mask(li), 1, lp.mask_elems)
-                g = g * m.reshape(g.shape).astype(g.dtype)
+                packed_mask = reader.get_mask(li)
+                with jax.named_scope(scopes.RELU_MASK):
+                    m = packmod.unpack(packed_mask, 1, lp.mask_elems)
+                    g = g * m.reshape(g.shape).astype(g.dtype)
             # transpose of the output-side Â product (gcn applies it
             # after the linear): swap the edge list's src/dst roles
-            gz = g if sage else _spmm(g, dst, src, gcn_w, n)
+            gz = g
+            if not sage:
+                with jax.named_scope(scopes.AGG_BWD):
+                    gz = _spmm(g, dst, src, gcn_w, n)
             g2 = gz.reshape(-1, gz.shape[-1])
-            if lp.cfg is None:
-                x_hat = reader.get_raw(li)
-                x2 = x_hat.reshape(-1, x_hat.shape[-1])
-                dw = x2.T @ g2
-                xdtype = x_hat.dtype
-            else:
-                # fused path: stash dequantized in the backward matmul's
-                # prologue (no f32 reconstruction round-trips HBM)
-                ct = reader.get_ct(li)
-                dw = decompress_matmul(ct, g2, fused=fused)
-                xdtype = ct.dtype
-            dparams[li] = {"w": dw.astype(p["w"].dtype),
-                           "b": jnp.sum(gz, axis=0).astype(p["b"].dtype)}
-            gx = (gz @ p["w"].T).astype(xdtype)
+            saved = (reader.get_raw(li) if lp.cfg is None
+                     else reader.get_ct(li))
+            with jax.named_scope(scopes.DENSE):
+                if lp.cfg is None:
+                    dw = saved.reshape(-1, saved.shape[-1]).T @ g2
+                else:
+                    # fused path: stash dequantized in the backward
+                    # matmul's prologue (no f32 reconstruction round-trips
+                    # HBM)
+                    dw = decompress_matmul(saved, g2, fused=fused)
+                dparams[li] = {"w": dw.astype(p["w"].dtype),
+                               "b": jnp.sum(gz, axis=0).astype(p["b"].dtype)}
+                gx = (gz @ p["w"].T).astype(saved.dtype)
             if sage:
                 d = gx.shape[1] // 2
-                gh = gx[:, :d] + _spmm(gx[:, d:], dst, src, mean_w, n)
+                with jax.named_scope(scopes.AGG_BWD):
+                    agg = _spmm(gx[:, d:], dst, src, mean_w, n)
+                gh = gx[:, :d] + agg
             else:
                 gh = gx
         dfeats = gh * nm[:, None]

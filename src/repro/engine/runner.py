@@ -8,11 +8,14 @@ refresh as a plan-recompile hook.  Everything policy-shaped lives in the
 plan and its compiler.
 
 Observability (the plan's :class:`~repro.obs.policy.ObsPolicy`) wraps
-the loop from the outside: spans around plan compile / epochs / autoprec
-re-solves, a recompile counter, and the opt-in quant-health probe on its
-epoch cadence.  All of it is host-side or a separate jitted pass — the
-training step's jaxpr is untouched, so obs-on runs are bit-identical to
-obs-off (gated in ``tests/test_obs.py``).
+the loop from the outside: spans around plan compile / epochs (split
+into ``epoch/data``, the plan's epoch inputs, and ``epoch/dispatch``,
+the counter's transfer and the step call) / autoprec re-solves, a
+recompile counter, and the opt-in quant-health probe on its epoch
+cadence.  Inside a ``jax.profiler`` trace the spans land on the host
+plane beside the device's ops.  All of it is host-side or a separate
+jitted pass — the training step's jaxpr is untouched, so obs-on runs
+are bit-identical to obs-off (gated in ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
@@ -119,10 +122,11 @@ def run(g, cfg, plan: ExecutionPlan | None = None, opt=None, *,
                             compiled = compiled.recompile(cfg)
                         obs.counter("engine/recompiles").inc()
                 with obs.span("epoch", epoch=epoch):
-                    data = compiled.epoch_data(order_rng)
-                    params, state, loss = compiled.step(params, state,
-                                                        jnp.asarray(epoch),
-                                                        *data)
+                    with obs.span("epoch/data"):
+                        data = compiled.epoch_data(order_rng)
+                    with obs.span("epoch/dispatch"):
+                        params, state, loss = compiled.step(
+                            params, state, jnp.asarray(epoch), *data)
                 if obs.quant_due(epoch):
                     with obs.span("obs/quant_probe", epoch=epoch):
                         obs.quant_probe(params, _probe_graph(compiled, gt),

@@ -27,6 +27,7 @@ from repro.core import pack as packmod
 from repro.core.act_compress import compressed_matmul, zero_ct  # noqa: F401
 from repro.core.compressor import CompressionConfig
 from repro.engine.seeds import layer_seed
+from repro.obs import scopes
 
 
 # ------------------------------------------------------------- 1-bit ReLU
@@ -39,14 +40,16 @@ def _relu_fwd(z):
     # Pack the sign bits of the *flattened* tensor as one row: rank-agnostic
     # (scalars, vectors, (N, F) maps, stacked/batched rank>=3 inputs) and at
     # most 31 wasted bits total, vs one word per row of a 2-D reshape.
-    mask = packmod.pack((z > 0).astype(jnp.int32).reshape(1, -1), 1)
+    with jax.named_scope(scopes.RELU_MASK):
+        mask = packmod.pack((z > 0).astype(jnp.int32).reshape(1, -1), 1)
     return jnp.maximum(z, 0.0), (mask, z.shape)
 
 
 def _relu_bwd(res, g):
     mask, shape = res
-    m = packmod.unpack(mask, 1, int(np.prod(shape, dtype=np.int64)))
-    return (g * m.reshape(shape).astype(g.dtype),)
+    with jax.named_scope(scopes.RELU_MASK):
+        m = packmod.unpack(mask, 1, int(np.prod(shape, dtype=np.int64)))
+        return (g * m.reshape(shape).astype(g.dtype),)
 
 
 relu_1bit.defvjp(_relu_fwd, _relu_bwd)

@@ -102,4 +102,5 @@ def flash_attention_call(q, k, v, *, causal: bool = True,
             pltpu.VMEM((blk_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

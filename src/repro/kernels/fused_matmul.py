@@ -145,6 +145,7 @@ def _build_matmul_quant(m, d, n, bits, group_size, levels, tm, tn,
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="matmul_quant",
     )
 
 
@@ -253,5 +254,6 @@ def dequant_matmul_call(packed, zero, rng, g2d, bits: int, group_size: int,
         out_specs=pl.BlockSpec((1, d, tn), lambda j, k: (k, 0, j)),
         out_shape=jax.ShapeDtypeStruct((k_tiles, d, n_pad), jnp.float32),
         interpret=interpret,
+        name="dequant_matmul",
     )(packed, zero, rng, gp)
     return _tree_sum(parts)[:, :n]

@@ -150,6 +150,7 @@ def quant_pack_call(x2d, bits: int, seed, levels=None, *,
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quant_pack",
     )(seed_arr, xp)
     return packed[:n], zero[:n], rng[:n]
 
@@ -177,5 +178,6 @@ def dequant_unpack_call(packed, zero, rng, bits: int, group_size: int,
         out_specs=pl.BlockSpec((rows_per_tile, group_size), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, group_size), jnp.float32),
         interpret=interpret,
+        name="dequant_unpack",
     )(packed, zero, rng)
     return out[:n]

@@ -65,6 +65,7 @@ def _call(x2d, seed, n_out: int, r_dim: int, transpose: bool,
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x2d.shape[0], n_out), jnp.float32),
         interpret=interpret,
+        name="irp_kernel" if transpose else "rp_kernel",
     )(seed_arr, x2d)[:m]
 
 

@@ -106,8 +106,7 @@ def _graph_main(args):
                       f"{row['ratio']:.2f} {100 * row['sat_rate']:.1f}%")
         if args.trace_out:
             paths = obs.export(args.trace_out)
-            print(f"obs trace: {paths['jsonl']} (spans) + "
-                  f"{paths['chrome']} (load at ui.perfetto.dev)")
+            print(f"obs trace: {paths['chrome']} (load at ui.perfetto.dev)")
     cfg = r.get("cfg", cfg)   # autoprec may have re-allocated per-layer bits
     rep = activation_memory_report(g, cfg, batch_nodes=r["batch_nodes"],
                                    plan=plan,
@@ -211,9 +210,9 @@ def main(argv=None):
                          "compression is on — the per-layer quant-health "
                          "probe (graph engines; bit-identical to obs-off)")
     ap.add_argument("--trace-out", default=None, metavar="BASE",
-                    help="with --obs: export the span trace to BASE.jsonl "
-                         "and BASE.trace.json (Chrome trace_event — load "
-                         "at ui.perfetto.dev)")
+                    help="with --obs: export the span trace to "
+                         "BASE.trace.json (Chrome trace_event — load at "
+                         "ui.perfetto.dev)")
     ap.add_argument("--obs-quant-every", type=int, default=10, metavar="N",
                     help="with --obs: run the quant-health probe every N "
                          "epochs")

@@ -4,9 +4,13 @@ Three layers, all default-off and all read-only taps (obs-on training is
 bit-identical to obs-off — gated in ``tests/test_obs.py``):
 
 * :mod:`~repro.obs.trace` — nested spans (plan compile, epochs, mesh
-  rounds, autoprec re-solves, pager fetch waits), exported as JSONL and
-  Chrome ``trace_event`` JSON (Perfetto-loadable), plus the repo-wide
+  rounds, autoprec re-solves, pager fetch waits), exported as Chrome
+  ``trace_event`` JSON (Perfetto-loadable) and, inside a
+  ``jax.profiler`` trace, also on its host plane; plus the repo-wide
   :func:`~repro.obs.trace.stopwatch` timing idiom;
+* :mod:`~repro.obs.scopes` — the names of the hot path's layers, opened
+  as ``jax.named_scope`` in the compiled step (device traces carry
+  them);
 * :mod:`~repro.obs.metrics` — counters / gauges / windowed histograms
   with shared null singletons when disabled (arena occupancy, pager
   overlap, halo bytes, recompile counts);

@@ -94,19 +94,17 @@ class ObsSession:
 
     # -------------------------------------------------------------- export
     def export(self, base_path) -> dict:
-        """Write the trace as ``<base>.jsonl`` + ``<base>.trace.json``
-        (the latter loads directly in Perfetto); returns the paths."""
+        """Write the trace as ``<base>.trace.json`` (Chrome
+        ``trace_event``, loads directly in Perfetto); returns its path."""
         if self.tracer is None:
             return {}
         p = pathlib.Path(base_path)
-        if p.suffix in (".jsonl", ".json"):
+        if p.suffix == ".json":
             p = p.with_suffix("")
         p.parent.mkdir(parents=True, exist_ok=True)
-        jsonl = p.with_suffix(".jsonl")
         chrome = p.with_suffix(".trace.json")
-        self.tracer.export_jsonl(jsonl)
         self.tracer.export_chrome(chrome)
-        return {"jsonl": str(jsonl), "chrome": str(chrome)}
+        return {"chrome": str(chrome)}
 
     def summary(self) -> dict:
         out: dict = {"policy": dataclasses.asdict(self.policy)}

@@ -1,10 +1,16 @@
-"""Host-side span tracer: nested wall-clock spans, exported as JSONL and
-Chrome ``trace_event`` JSON (loadable at https://ui.perfetto.dev, and
-composable with ``jax.profiler`` device traces — same timeline format).
+"""Host-side span tracer: nested wall-clock spans, exported as Chrome
+``trace_event`` JSON (loadable at https://ui.perfetto.dev).
+
+Each span is also a ``jax.profiler.TraceAnnotation``: inside a
+``jax.profiler`` trace it lands on the profile's host plane, on the same
+clock as the device's ops, so an idle stretch of the device can be put
+down to what the program's host loop was doing at the time.  Outside a
+profiler trace the annotation costs next to nothing.
 
 Everything here is host-side ``time.perf_counter`` bookkeeping; none of
 it may run inside jitted code (the seed-lint ``jit-host-nondeterminism``
-rule enforces that repo-wide).  The module keeps one *active* tracer
+rule enforces that repo-wide).  JAX is imported on the first span, not
+at import.  The module keeps one *active* tracer
 (:func:`set_tracer` / :func:`get_tracer`): producers call the
 module-level :func:`span` / :func:`stopwatch` and emit spans only when a
 tracer is installed — with none installed both are shared no-op objects,
@@ -39,13 +45,13 @@ class Tracer:
 
     def __init__(self):
         self._origin = time.perf_counter()
-        #: wall-clock epoch of the origin, for aligning with external traces
-        self.origin_unix_s = time.time()
         self.spans: list[Span] = []
         self._stack: list[int] = []
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
+        from jax.profiler import TraceAnnotation
+
         idx = len(self.spans)
         s = Span(name, time.perf_counter() - self._origin, 0.0,
                  depth=len(self._stack),
@@ -54,17 +60,13 @@ class Tracer:
         self.spans.append(s)
         self._stack.append(idx)
         try:
-            yield s
+            with TraceAnnotation(name):
+                yield s
         finally:
             self._stack.pop()
             s.dur = time.perf_counter() - self._origin - s.t0
 
     # ------------------------------------------------------------- export
-    def jsonl_events(self) -> list[dict]:
-        return [{"name": s.name, "ts_s": s.t0, "dur_s": s.dur,
-                 "depth": s.depth, "parent": s.parent, "args": s.args}
-                for s in self.spans]
-
     def chrome_trace(self) -> dict:
         """Chrome ``trace_event`` dict: complete ("X") events in µs."""
         events = [{"name": s.name, "cat": "repro", "ph": "X",
@@ -72,10 +74,6 @@ class Tracer:
                    "pid": 0, "tid": 0, "args": s.args}
                   for s in self.spans]
         return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def export_jsonl(self, path) -> None:
-        lines = [json.dumps(e) for e in self.jsonl_events()]
-        pathlib.Path(path).write_text("\n".join(lines) + "\n")
 
     def export_chrome(self, path) -> None:
         pathlib.Path(path).write_text(json.dumps(self.chrome_trace()))

@@ -1,10 +1,14 @@
 """repro.obs: obs-on training is bit-identical to obs-off across every
-engine, spans nest well-formed and export to valid Chrome/JSONL traces,
+engine, spans nest well-formed, export to a valid Chrome trace and land
+on a ``jax.profiler`` trace's host plane,
 the quant-health channel's measured SR variance agrees with its own
 conditional expectation and with the Eq. 10 prediction, and the pager's
 windowed overlap stat is live."""
 import dataclasses
+import glob
 import json
+import pathlib
+import sys
 
 import numpy as np
 import jax
@@ -137,20 +141,54 @@ def test_trace_exports_are_schema_valid(g, tmp_path):
     plan = dataclasses.replace(_plans("jnp")["full"], obs=OBS)
     r = run(g, _cfg(g), plan, n_epochs=2, seed=0)
     paths = r["obs"].export(tmp_path / "trace")
-    # JSONL: one json object per line, span schema
-    lines = (tmp_path / "trace.jsonl").read_text().strip().split("\n")
-    events = [json.loads(ln) for ln in lines]
-    assert len(events) == len(r["obs"].tracer.spans)
-    for e in events:
-        assert set(e) == {"name", "ts_s", "dur_s", "depth", "parent", "args"}
-    # Chrome trace_event: what Perfetto loads
+    # the Chrome trace_event file is the one export: what Perfetto loads
+    assert paths == {"chrome": str(tmp_path / "trace.trace.json")}
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["trace.trace.json"]
     chrome = json.loads((tmp_path / "trace.trace.json").read_text())
     assert set(chrome) == {"traceEvents", "displayTimeUnit"}
+    spans = r["obs"].tracer.spans
+    assert [ev["name"] for ev in chrome["traceEvents"]] == [
+        s.name for s in spans]
     for ev in chrome["traceEvents"]:
         assert ev["ph"] == "X" and ev["cat"] == "repro"
         assert isinstance(ev["ts"], float) and isinstance(ev["dur"], float)
         assert ev["ts"] >= 0.0 and ev["dur"] >= 0.0
-    assert paths["chrome"].endswith(".trace.json")
+
+
+def _profiled_run(g, tmp, obs):
+    """Three tiny epochs under ``jax.profiler.trace``; the host plane's
+    events as ``[name, start_ns, dur_ns]``, read the way the benchmark
+    reads a chip's trace."""
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
+                           / "bench"))
+    import tracecut
+
+    plan = ExecutionPlan(obs=obs)
+    with jax.profiler.trace(str(tmp)):
+        run(g, _cfg(g), plan, n_epochs=3, seed=0)
+    (path,) = glob.glob(f"{tmp}/plugins/profile/*/*.xplane.pb")
+    return tracecut.load_xplane(path)["host"]
+
+
+def test_spans_land_on_the_profilers_host_plane(g, tmp_path):
+    names = ("plan/compile", "epoch", "epoch/data", "epoch/dispatch")
+    host = _profiled_run(g, tmp_path / "on", ObsPolicy(enabled=True))
+    # (start, end) in ns, an enclosing span before the spans it holds
+    evs = sorted(((s, s + d), n) for n, s, d in host if n in names)
+    evs.sort(key=lambda e: (e[0][0], -e[0][1]))
+    assert [n for _, n in evs] == ["plan/compile"] + [
+        "epoch", "epoch/data", "epoch/dispatch"] * 3
+    spans = [iv for iv, _ in evs]
+    # compile ends before the first epoch; epochs follow one another;
+    # each epoch holds its data step, then its dispatch step
+    assert spans[0][1] <= spans[1][0]
+    for k in range(3):
+        ep, data, disp = spans[1 + 3 * k: 4 + 3 * k]
+        assert ep[0] <= data[0] <= data[1] <= disp[0] <= disp[1] <= ep[1]
+        if k:
+            assert spans[3 * k - 2][1] <= ep[0]
+    off = _profiled_run(g, tmp_path / "off", ObsPolicy())
+    assert not [n for n, _, _ in off if n in names]
 
 
 def test_stopwatch_measures_without_tracer():

@@ -8,6 +8,8 @@ times; the chip smoke test (``chip_smoke.py``) does.  About two seconds
 each.
 """
 import os
+import pathlib
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,10 @@ from repro.kernels import autotune
 from repro.kernels import fused_matmul as fk
 from repro.kernels import quant_blockwise as qk
 from repro.kernels import rp_matmul as rk
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
+                       / "bench"))
+import tracecut  # noqa: E402
 
 #: ogbn-arxiv node count: ragged against every tile (not a multiple of 8).
 ROWS = 169_343
@@ -50,12 +56,20 @@ def _compiled_text(chip, fn, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_kinds(text: str) -> set:
+    """The benchmark's kinds (``tracecut.KERNEL_KINDS``) of the Pallas
+    calls in a compiled module, which it finds by each call's ``name=``."""
+    return {op.kernel for op in tracecut.hlo_ops(text).values()
+            if op.kind == "kernel"}
+
+
 @pytest.mark.parametrize("bits", [2, 8])
 def test_quant_pack_compiles(chip, bits):
     text = _compiled_text(
         chip, lambda x, s: qk.quant_pack_call(x, bits, s),
         ((ROWS, G), jnp.float32), ((), jnp.uint32))
     assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"quant"}
 
 
 @pytest.mark.parametrize("bits", [2, 8])
@@ -66,6 +80,7 @@ def test_dequant_unpack_compiles(chip, bits):
         ((ROWS, w), jnp.uint32), ((ROWS, 1), jnp.float32),
         ((ROWS, 1), jnp.float32))
     assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"dequant"}
 
 
 def test_matmul_quant_compiles(chip):
@@ -76,6 +91,7 @@ def test_matmul_quant_compiles(chip):
                                                    tm=tm, tn=tn),
         ((ROWS, d), jnp.float32), ((d, n), jnp.float32), ((), jnp.uint32))
     assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"fused_fwd"}
 
 
 @pytest.mark.parametrize("d,n", [(256, 256), (512, 40)])
@@ -91,6 +107,7 @@ def test_dequant_matmul_compiles_with_picked_tiles(chip, d, n):
         ((nb, w), jnp.uint32), ((nb, 1), jnp.float32),
         ((nb, 1), jnp.float32), ((ROWS, n), jnp.float32))
     assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"fused_bwd"}
 
 
 def test_rp_project_compiles(chip):
@@ -98,3 +115,13 @@ def test_rp_project_compiles(chip):
         chip, lambda x, s: rk.rp_project_call(x, s, 128),
         ((ROWS, 256), jnp.float32), ((), jnp.uint32))
     assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"rp"}
+
+
+def test_irp_project_compiles(chip):
+    text = _compiled_text(
+        chip, lambda x, s: rk.irp_project_call(x, s, 256),
+        ((ROWS, 128), jnp.float32), ((), jnp.uint32))
+    assert "tpu_custom_call" in text
+    assert _kernel_kinds(text) == {"rp"}
+    assert "/irp_kernel/" in text
