@@ -118,7 +118,7 @@ def sage_layer0_input(g):
 
     from repro.graph.models import spmm
 
-    agg = spmm(g.features, g.edge_src, g.edge_dst, g.mean_weight, g.n_nodes)
+    agg = spmm(g.features, g.aggregation[1])
     return jnp.concatenate([g.features, agg], axis=1)
 
 

@@ -22,13 +22,16 @@ sign mask) goes through the policy's writer.
 Backward: a manual layer-by-layer reverse walk mirroring what autodiff
 produces on the per-op path — ``dx = g @ wᵀ`` exact, ``dw = x̂ᵀ g`` at
 the reconstruction (EXACT's estimator), ReLU via the saved sign mask,
-and the Â-product transposed by swapping the edge list's src/dst roles.
+and the Â-product transposed through the operand's ``T`` (the other
+direction's padded-row layout, or the edge list with src/dst swapped),
+the same product ``spmm``'s own VJP takes.
 Arena readers prefetch layer ``li-1``'s segments before layer ``li``'s
 gradient math so host→device copies run one layer ahead
 (double-buffered); the per-tensor reader's prefetch is a no-op.
 
-Cotangents are returned for params and features; edge weights and the
-padding mask are non-differentiable graph constants (zero cotangents) —
+Cotangents are returned for params and features; the aggregation
+operands and the padding mask are non-differentiable graph constants
+(zero cotangents) —
 the training engines only ever differentiate with respect to params.
 """
 from __future__ import annotations
@@ -40,10 +43,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pack as packmod
-from repro.core.act_compress import zero_ct
 from repro.core.compressor import compress_matmul, decompress_matmul
 from repro.engine import seeds
 from repro.engine.plan import StashPolicy
+from repro.graph.adjacency import EdgeList, zero_cotangent
 from repro.obs import scopes
 from repro.offload import engine as stash_engine
 from repro.offload.arena import StashPlan
@@ -80,30 +83,29 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
     sage = cfg.arch == "sage"
     L = len(plan.layers)
 
-    def layer_input(h, src, dst, mean_w, n):
+    def layer_input(h, mean_adj):
         if not sage:
             return h
         with jax.named_scope(scopes.AGG_FWD):
-            agg = _spmm(h, src, dst, mean_w, n)
+            agg = _spmm(h, mean_adj)
         return jnp.concatenate([h, agg], axis=1)
 
     @jax.custom_vjp
-    def f(params, feats, src, dst, gcn_w, mean_w, seed, nm):
+    def f(params, feats, gcn_adj, mean_adj, seed, nm):
         # primal path (un-differentiated calls): the per-op forward is
         # value-identical and stash-free (compressed_matmul / relu_1bit
         # primals are plain x @ w / maximum), so don't re-state the layer
         # math a third time
-        return gnn_forward(params, (feats, src, dst, gcn_w, mean_w), cfg,
+        return gnn_forward(params, (feats, gcn_adj, mean_adj), cfg,
                            seed=seed, node_mask=nm)
 
-    def f_fwd(params, feats, src, dst, gcn_w, mean_w, seed, nm):
-        n = feats.shape[0]
+    def f_fwd(params, feats, gcn_adj, mean_adj, seed, nm):
         writer = stash_engine.make_writer(plan, stash.placement, seed,
                                           kind=stash.kind)
         h = feats * nm[:, None]
         for li, p in enumerate(params):
             lseed = seeds.layer_seed(seed, li)
-            x = layer_input(h, src, dst, mean_w, n)
+            x = layer_input(h, mean_adj)
             comp = per_layer[li]
             with jax.named_scope(scopes.DENSE):
                 if comp is None:
@@ -122,7 +124,7 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
                 writer.put_ct(li, ct)
             if not sage:
                 with jax.named_scope(scopes.AGG_FWD):
-                    z = _spmm(z, src, dst, gcn_w, n)
+                    z = _spmm(z, gcn_adj)
             if li < L - 1:
                 with jax.named_scope(scopes.RELU_MASK):
                     mask = packmod.pack(
@@ -131,11 +133,10 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
                 with jax.named_scope(scopes.DENSE):
                     z = jnp.maximum(z, 0.0)
             h = z * nm[:, None]
-        return h, (params, src, dst, gcn_w, mean_w, nm, writer.residual())
+        return h, (params, gcn_adj, mean_adj, nm, writer.residual())
 
     def f_bwd(res, gy):
-        params, src, dst, gcn_w, mean_w, nm, residual = res
-        n = nm.shape[0]
+        params, gcn_adj, mean_adj, nm, residual = res
         reader = stash_engine.make_reader(plan, stash.placement, residual,
                                           kind=stash.kind)
         reader.prefetch(L - 1)
@@ -153,11 +154,11 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
                     m = packmod.unpack(packed_mask, 1, lp.mask_elems)
                     g = g * m.reshape(g.shape).astype(g.dtype)
             # transpose of the output-side Â product (gcn applies it
-            # after the linear): swap the edge list's src/dst roles
+            # after the linear)
             gz = g
             if not sage:
                 with jax.named_scope(scopes.AGG_BWD):
-                    gz = _spmm(g, dst, src, gcn_w, n)
+                    gz = _spmm(g, gcn_adj.T)
             g2 = gz.reshape(-1, gz.shape[-1])
             saved = (reader.get_raw(li) if lp.cfg is None
                      else reader.get_ct(li))
@@ -175,14 +176,15 @@ def _build(cfg, plan: StashPlan, stash: StashPolicy, fused: str = "auto"):
             if sage:
                 d = gx.shape[1] // 2
                 with jax.named_scope(scopes.AGG_BWD):
-                    agg = _spmm(gx[:, d:], dst, src, mean_w, n)
-                gh = gx[:, :d] + agg
+                    # gx[:, :d] + Âᵀ·gx[:, d:], each row's sum started
+                    # at its direct term, as autodiff's scatter takes it
+                    gh = mean_adj.T.add_aggregate(gx[:, :d], gx[:, d:])
             else:
                 gh = gx
         dfeats = gh * nm[:, None]
-        return (dparams, dfeats, zero_ct(src), zero_ct(dst),
-                jnp.zeros_like(gcn_w), jnp.zeros_like(mean_w),
-                np.zeros((), jax.dtypes.float0), jnp.zeros_like(nm))
+        return (dparams, dfeats, zero_cotangent(gcn_adj),
+                zero_cotangent(mean_adj), np.zeros((), jax.dtypes.float0),
+                jnp.zeros_like(nm))
 
     f.defvjp(f_fwd, f_bwd)
     return f
@@ -196,11 +198,11 @@ def stash_gnn_forward(params, graph, cfg, plan: StashPlan,
     if len(plan.layers) != cfg.n_layers:
         raise ValueError(f"plan has {len(plan.layers)} layers for a "
                          f"{cfg.n_layers}-layer model")
-    feats, src, dst, gcn_w, mean_w = graph
+    feats, gcn_adj, mean_adj = graph
     nm = (jnp.ones((feats.shape[0],), feats.dtype) if node_mask is None
           else node_mask.astype(feats.dtype))
     fn = _build(cfg, plan, stash, fused)
-    return fn(params, feats, src, dst, gcn_w, mean_w,
+    return fn(params, feats, gcn_adj, mean_adj,
               jnp.asarray(seed, jnp.uint32), nm)
 
 
@@ -239,7 +241,8 @@ def mesh_gnn_forward(params, feats, esrc, edst, gcn_w, mean_w, nm, send_idx,
 
     The same per-layer math as :func:`repro.graph.models.gnn_forward`
     composed from the per-op ``custom_vjp`` stack (``compressed_matmul``,
-    ``relu_1bit``, ``spmm``) — bit-identical gradients to the engine's
+    ``relu_1bit``) and ``spmm`` over edge lists — bit-identical gradients
+    to the engine's
     stash forward per the PR 5 parity gate — with one addition: before
     each aggregation, :func:`repro.parallel.halo.halo_exchange` extends
     the aggregated tensor with the round-mates' boundary rows.  GCN
@@ -258,6 +261,8 @@ def mesh_gnn_forward(params, feats, esrc, edst, gcn_w, mean_w, nm, send_idx,
 
     per_layer = cfg.layer_compression()
     n = feats.shape[0]
+    gcn_adj = EdgeList(esrc, edst, gcn_w, n)
+    mean_adj = EdgeList(esrc, edst, mean_w, n)
     seed = jnp.asarray(seed, jnp.uint32)
     h = feats * nm[:, None]
     for li, p in enumerate(params):
@@ -266,10 +271,9 @@ def mesh_gnn_forward(params, feats, esrc, edst, gcn_w, mean_w, nm, send_idx,
         if cfg.arch == "gcn":
             z = (h @ p["w"] if comp is None
                  else compressed_matmul(h, p["w"], lseed, comp)) + p["b"]
-            z = spmm(halo_exchange(z, send_idx, axis), esrc, edst, gcn_w, n)
+            z = spmm(halo_exchange(z, send_idx, axis), gcn_adj)
         else:  # sage
-            agg = spmm(halo_exchange(h, send_idx, axis), esrc, edst,
-                       mean_w, n)
+            agg = spmm(halo_exchange(h, send_idx, axis), mean_adj)
             x = jnp.concatenate([h, agg], axis=1)
             z = (x @ p["w"] if comp is None
                  else compressed_matmul(x, p["w"], lseed, comp)) + p["b"]

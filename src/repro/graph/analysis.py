@@ -75,19 +75,18 @@ def _iter_layer_inputs(params, graph, cfg: GNNConfig):
     concat, Â aggregation, interior ReLU — so the collectors cannot drift
     from what training actually saves.
     """
-    feats, src, dst, gcn_w, mean_w = graph
-    n = feats.shape[0]
+    feats, gcn_adj, mean_adj = graph
     h = feats
     for li, p in enumerate(params):
         if cfg.arch == "gcn":
             x = h
         else:
-            agg = spmm(h, src, dst, mean_w, n)
+            agg = spmm(h, mean_adj)
             x = jnp.concatenate([h, agg], axis=1)
         yield li, x
         z = x @ p["w"] + p["b"]
         if cfg.arch == "gcn":
-            z = spmm(z, src, dst, gcn_w, n)
+            z = spmm(z, gcn_adj)
         if li < len(params) - 1:
             z = jnp.maximum(z, 0.0)
         h = z

@@ -9,9 +9,12 @@ class prior, which is what the paper's accuracy-parity claims need.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 import jax.numpy as jnp
+
+from repro.graph.adjacency import PaddedRows, padded_rows
 
 
 @dataclasses.dataclass
@@ -40,13 +43,25 @@ class Graph:
     def n_edges(self) -> int:
         return int(self.edge_src.shape[0])
 
+    @functools.cached_property
+    def aggregation(self) -> tuple[PaddedRows, PaddedRows]:
+        """``(gcn, mean)``: the aggregation operands of the full graph,
+        its padded-row layout under the GCN and the mean weights, built
+        on first use and kept with the graph."""
+        gcn, mean = padded_rows(self.edge_src, self.edge_dst,
+                                (self.gcn_weight, self.mean_weight),
+                                self.n_nodes)
+        return gcn, mean
+
 
 def in_adjacency(edge_src, edge_dst, n_nodes: int):
     """CSR over *destination*: ``(nbr, starts)`` with the in-neighbors
     (message sources) of node ``u`` at ``nbr[starts[u]:starts[u+1]]``.
 
-    numpy-side helper for partitioners/samplers — the edge list itself stays
-    the device-side representation (``spmm`` consumes it directly)."""
+    numpy-side helper for partitioners/samplers.  A full graph aggregates
+    through its padded-row layout (:attr:`Graph.aggregation`), built from
+    the same stable destination sort; partition batches and the mesh
+    engine's tables aggregate their edge lists directly."""
     src = np.asarray(edge_src)
     dst = np.asarray(edge_dst)
     order = np.argsort(dst, kind="stable")

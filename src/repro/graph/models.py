@@ -6,8 +6,9 @@ Compression placement matches EXACT/i-EXACT exactly:
 * the dense input of every linear is stored compressed
   (:func:`repro.core.compressed_matmul`) — RP + block-wise SR quant;
 * ReLU saves a packed 1-bit sign mask (:func:`relu_1bit`), never the tensor;
-* the sparse aggregation ``Â·`` is linear in H — its VJP needs only the edge
-  list and weights, so it stores no float activations at all.
+* the sparse aggregation ``Â·`` is linear in H — its VJP needs only the
+  graph's structure and weights (:mod:`repro.graph.adjacency`), so it
+  stores no float activations at all.
 
 The compress/decompress execution strategy is picked by
 ``CompressionConfig.impl`` (see :mod:`repro.core.backend`);
@@ -56,10 +57,12 @@ relu_1bit.defvjp(_relu_fwd, _relu_bwd)
 
 
 # ------------------------------------------------------------------ SpMM
-def spmm(h, src, dst, w, n_nodes: int):
-    """out[d] += w_e * h[s] over edges — the Â· product as segment-sum."""
-    msg = h[src] * w[:, None]
-    return jax.ops.segment_sum(msg, dst, num_segments=n_nodes)
+def spmm(h, adj):
+    """``out[d] += w_e * h[s]`` over the edges of ``adj``, an aggregation
+    operand of :mod:`repro.graph.adjacency`: a static graph's padded-row
+    layout (gather and add, its transpose as the VJP) or a traced edge
+    list (segment-sum).  ``adj.T`` is the transposed product."""
+    return adj.aggregate(h)
 
 
 # ----------------------------------------------------------------- model
@@ -150,7 +153,9 @@ def _maybe_compressed_matmul(x, w, comp: CompressionConfig | None, seed):
 
 def gnn_forward(params, graph, cfg: GNNConfig, seed=0, dropout_key=None,
                 node_mask=None, plan=None, offload=None):
-    """graph = (features, src, dst, gcn_w, mean_w).
+    """graph = (features, gcn_adj, mean_adj): the features and the
+    aggregation operands under the GCN and the mean weights
+    (:func:`graph_tuple`, ``SubgraphBatch.graph_tuple``).
 
     ``node_mask`` ((N,) f32, optional) marks valid rows of a padded subgraph
     batch: activations of masked-out rows are pinned to zero after every
@@ -173,8 +178,7 @@ def gnn_forward(params, graph, cfg: GNNConfig, seed=0, dropout_key=None,
         return arena_gnn_forward(params, graph, cfg, plan, seed=seed,
                                  node_mask=node_mask,
                                  policy=offload or "device")
-    feats, src, dst, gcn_w, mean_w = graph
-    n = feats.shape[0]  # static under jit
+    feats, gcn_adj, mean_adj = graph
     h = feats if node_mask is None else feats * node_mask[:, None]
     seed = jnp.asarray(seed, jnp.uint32)
     per_layer = cfg.layer_compression()
@@ -183,10 +187,9 @@ def gnn_forward(params, graph, cfg: GNNConfig, seed=0, dropout_key=None,
         comp = per_layer[li]
         if cfg.arch == "gcn":
             z = _maybe_compressed_matmul(h, p["w"], comp, lseed) + p["b"]
-            z = spmm(z, src, dst, gcn_w, n)
+            z = spmm(z, gcn_adj)
         else:  # sage
-            agg = spmm(h, src, dst, mean_w, n)
-            x = jnp.concatenate([h, agg], axis=1)
+            x = mean_adj.concat_aggregate(h)   # [h, Â·h]
             z = _maybe_compressed_matmul(x, p["w"], comp, lseed) + p["b"]
         if li < len(params) - 1:
             z = relu_1bit(z)
@@ -199,5 +202,6 @@ def gnn_forward(params, graph, cfg: GNNConfig, seed=0, dropout_key=None,
 
 
 def graph_tuple(g):
-    """Pull the jit-stable array tuple out of a Graph dataclass."""
-    return (g.features, g.edge_src, g.edge_dst, g.gcn_weight, g.mean_weight)
+    """The jit-stable ``(features, gcn_adj, mean_adj)`` of a full Graph:
+    its padded-row layout, built once per graph."""
+    return (g.features, *g.aggregation)

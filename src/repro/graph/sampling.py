@@ -31,6 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.graph.adjacency import EdgeList
 from repro.graph.data import Graph, in_adjacency
 
 
@@ -140,9 +141,13 @@ class SubgraphBatch:
         return int(self.edge_src.shape[0])
 
     def graph_tuple(self):
-        """The 5-tuple :func:`repro.graph.models.gnn_forward` consumes."""
-        return (self.features, self.edge_src, self.edge_dst,
-                self.gcn_weight, self.mean_weight)
+        """The ``(features, gcn_adj, mean_adj)`` that
+        :func:`repro.graph.models.gnn_forward` consumes: edge lists, as
+        the batch's edges are traced under the epoch's ``lax.scan``."""
+        n = self.n_nodes
+        return (self.features,
+                EdgeList(self.edge_src, self.edge_dst, self.gcn_weight, n),
+                EdgeList(self.edge_src, self.edge_dst, self.mean_weight, n))
 
 
 def stack_batches(batches: list[SubgraphBatch]) -> SubgraphBatch:

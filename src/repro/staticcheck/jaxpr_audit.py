@@ -175,6 +175,7 @@ def audit_forward(fwd, example_args, splan: StashPlan, mechanism: str,
 
 
 def _example_args(cfg, in_dim: int, n_nodes: int):
+    from repro.graph.adjacency import EdgeList
     from repro.graph.models import _dims
 
     f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32
@@ -183,8 +184,12 @@ def _example_args(cfg, in_dim: int, n_nodes: int):
     dims = _dims(cfg, in_dim)
     params = [{"w": sds((d_in * mult, d_out), f32), "b": sds((d_out,), f32)}
               for d_in, d_out in zip(dims[:-1], dims[1:])]
-    return (params, sds((n_nodes, in_dim), f32), sds((_EDGES,), i32),
-            sds((_EDGES,), i32), sds((_EDGES,), f32), sds((_EDGES,), f32),
+    # the forward is the same whatever the aggregation operand; an edge
+    # list is the one that needs only shapes
+    src, dst = sds((_EDGES,), i32), sds((_EDGES,), i32)
+    return (params, sds((n_nodes, in_dim), f32),
+            EdgeList(src, dst, sds((_EDGES,), f32), n_nodes),
+            EdgeList(src, dst, sds((_EDGES,), f32), n_nodes),
             sds((), u32), sds((n_nodes,), f32))
 
 

@@ -125,3 +125,44 @@ def test_irp_project_compiles(chip):
     assert "tpu_custom_call" in text
     assert _kernel_kinds(text) == {"rp"}
     assert "/irp_kernel/" in text
+
+
+@pytest.fixture(scope="module")
+def arxiv_rows():
+    """The padded-row layout of a graph of ogbn-arxiv's counts and the
+    benchmark's in-degree skew (``floor(N u^2)``), symmetrised, with a
+    self loop per node: 2,501,829 directed edges."""
+    import numpy as np
+
+    from repro.graph.adjacency import padded_rows
+
+    rng = np.random.default_rng(0)
+    src = rng.integers(0, ROWS, 1_166_243)
+    dst = np.minimum((ROWS * rng.random(src.size) ** 2).astype(np.int64),
+                     ROWS - 1)
+    loops = np.arange(ROWS)
+    s = np.concatenate([src, dst, loops])
+    d = np.concatenate([dst, src, loops])
+    deg = np.bincount(d, minlength=ROWS)
+    return padded_rows(s, d, [(1.0 / deg[d]).astype(np.float32)], ROWS)[0]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_padded_row_aggregation_compiles_at_arxiv_size(chip, arxiv_rows,
+                                                       direction):
+    """The layout's aggregation at 256 columns: no sort, no array of edge
+    length, and temporaries bounded by its steps (one gathered step of
+    2^18 rows, the accumulator and the result: 0.6 GB; a level gathered
+    at once would take 2.8 GB)."""
+    from repro.graph.adjacency import _fold
+
+    one = SingleDeviceSharding(chip)
+    plan = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        getattr(arxiv_rows, direction))
+    h = jax.ShapeDtypeStruct((ROWS, 256), jnp.float32, sharding=one)
+    compiled = jax.jit(_fold).lower(h, plan).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text
+    assert "2501829" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
