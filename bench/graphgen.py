@@ -14,6 +14,12 @@ sizes: exactly ``n_edges`` undirected edges with no self pair (a drawn
 self pair is moved to the next node), symmetrised, plus one self loop
 per node, so ``2 * n_edges + n_nodes`` directed edges, and split counts
 of ``int(share * n_nodes)``.
+
+A configuration whose generator sets ``structure_seed`` draws the edges
+and the labels from that seed alone, and the features, the split and
+the weights from the run's seed: every run then partitions the same
+graph, so a layout whose sizes follow the partition (the mesh's halo
+width) compiles once for all seeds.
 """
 from __future__ import annotations
 
@@ -23,10 +29,22 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-#: Multipliers of the split's affine bijection ``(a * v + b) mod N``:
-#: primes small enough that ``a * v`` stays inside int32 for the node
-#: counts configured here; one that divides N is skipped.
+#: Multipliers of the split's affine bijection ``(a * v + b) mod N``;
+#: one that divides N is skipped.  Below :data:`_WIDE_NODES` nodes
+#: ``a * v + b`` stays inside int32; from there on the product is taken
+#: modulo N by 16-bit halves (:func:`_mulmod`).
 _SPLIT_PRIMES = (7919, 7927, 7933, 7937, 7949, 7951, 7963, 7993)
+_WIDE_NODES = 2 ** 31 // (max(_SPLIT_PRIMES) + 1)
+
+
+def _mulmod(a, v, n: int):
+    """``(a * v) mod n`` in int32 for ``a < 2**15``, ``0 <= v < n <
+    2**30``: ``v = hi * 2**16 + lo``, and ``hi * a`` is carried up by 16
+    doublings, each reduced modulo ``n``."""
+    hi = (a * (v >> 16)) % n
+    for _ in range(16):
+        hi = (2 * hi) % n
+    return (hi + (a * (v & 0xFFFF)) % n) % n
 
 
 def seed_key(seed: int):
@@ -44,14 +62,18 @@ def seed_key(seed: int):
                                    "val_share"))
 def make_graph(key, *, n_nodes: int, n_edges: int, n_feats: int,
                n_classes: int, homophily: float, feature_noise: float,
-               train_share: float, val_share: float) -> dict:
+               train_share: float, val_share: float,
+               structure_key=None) -> dict:
     """Arrays of one graph: ``features`` (N, F) f32, ``labels`` (N,) i32,
     ``src``/``dst`` (2E + N,) i32, ``mean_w``/``gcn_w`` (2E + N,) f32 and
-    the ``train``/``val``/``test`` masks (N,) bool."""
+    the ``train``/``val``/``test`` masks (N,) bool.  ``structure_key``,
+    where given, draws the labels and the edges in ``key``'s place."""
     n, e, c = n_nodes, n_edges, n_classes
-    if n * max(_SPLIT_PRIMES) >= 2 ** 31:
+    if n >= 2 ** 30:
         raise ValueError(f"n_nodes={n} is too large for the split")
     k = jax.random.split(key, 8)
+    if structure_key is not None:
+        k = jnp.concatenate([jax.random.split(structure_key, 8)[:5], k[5:]])
     offset = jax.random.randint(k[0], (), 0, c, jnp.int32)
     idx = jnp.arange(n, dtype=jnp.int32)
     labels = (idx + offset) % c
@@ -84,7 +106,10 @@ def make_graph(key, *, n_nodes: int, n_edges: int, n_feats: int,
                          jnp.int32)
     a = primes[jax.random.randint(k[7], (), 0, primes.shape[0])]
     b = jax.random.randint(jax.random.fold_in(k[7], 1), (), 0, n, jnp.int32)
-    rank = (a * idx + b) % n
+    if n < _WIDE_NODES:
+        rank = (a * idx + b) % n
+    else:
+        rank = (_mulmod(a, idx, n) + b) % n
     n_tr, n_va = int(train_share * n), int(val_share * n)
     return {"features": feats, "labels": labels, "src": s_all,
             "dst": d_all, "mean_w": mean_w, "gcn_w": gcn_w,
@@ -96,13 +121,16 @@ def make_graph(key, *, n_nodes: int, n_edges: int, n_feats: int,
 def graph_from_config(config: dict, seed: int) -> dict:
     """:func:`make_graph` at a configuration file's counts."""
     gr, gen = config["graph"], config["generator"]
+    structure = gen.get("structure_seed")
     return make_graph(seed_key(seed), n_nodes=gr["n_nodes"],
                       n_edges=gr["n_edges"], n_feats=gr["n_feats"],
                       n_classes=gr["n_classes"],
                       homophily=gen["homophily"],
                       feature_noise=gen["feature_noise"],
                       train_share=gen["split"][0],
-                      val_share=gen["split"][1])
+                      val_share=gen["split"][1],
+                      structure_key=(None if structure is None
+                                     else seed_key(structure)))
 
 
 @partial(jax.jit, static_argnames=("dims",))

@@ -6,16 +6,22 @@ Everything cell-specific is found by name: the configuration's file
 ``bench/workloads/<cell>.json`` and each per-layer metric's reader at
 ``bench/metrics/<metric>.py``.
 
+A traffic's ``mode`` is ``"full"`` (full-graph training on one chip) or
+``"mesh"`` (the graph cut into ``n_parts`` partitions, one a chip, with a
+halo exchange before each aggregation: every edge is kept, so the check
+is the same full-graph reference).
+
 A run:
 
 1. set-up: builds the graph and the weights on the device from the seed,
-   compiles the program's full-graph epoch step
-   (``repro.engine.compile.compile_plan``), reads its memory analysis,
-   and drives that one compiled step through its first three epochs,
-   keeping what the correctness check needs;
-2. window: dispatches the same compiled step back to back for
-   ``seconds``, one epoch in flight behind the one being waited on, and
-   ends with ``block_until_ready``;
+   lowers the traffic's plan (``repro.engine.compile.compile_plan``),
+   compiles its step (a full graph's epoch step, or the mesh's round
+   step, which the plan's own epoch step then calls), reads the
+   compiled program's memory analysis, and drives the plan's epoch step
+   through its first three epochs, keeping what the check needs;
+2. window: calls the same epoch step back to back for ``seconds``, one
+   epoch in flight behind the one being waited on, and ends with
+   ``block_until_ready``;
 3. check: with the program's state freed, runs the plain reference's
    first three steps from the same graph and weights and compares.
 """
@@ -37,6 +43,23 @@ ROOT = BENCH.parent
 
 class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
+
+
+def check_devices(chips: int, require_tpu: bool = True) -> list:
+    """JAX's devices, where they hold the cell's ``chips`` (and, with
+    ``require_tpu``, are TPUs); :class:`NoChip` otherwise.  A mesh never
+    falls back to fewer devices than its partitions: that would drop
+    every edge between them and compute something else."""
+    import jax
+
+    devs = jax.devices()
+    if require_tpu and devs[0].platform != "tpu":
+        raise NoChip(f"JAX's first device is {devs[0].platform!r}, "
+                     "not a TPU")
+    if len(devs) < chips:
+        raise NoChip(f"the cell needs {chips} chips; JAX found "
+                     f"{len(devs)}")
+    return devs
 
 
 # ------------------------------------------------------------ files by name
@@ -90,16 +113,28 @@ def load_reader(metric: str):
 # ------------------------------------------------------------- the program
 def program_objects(files: dict, graph: dict):
     """The program's graph, model config, plan and optimizer for a cell."""
+    import dataclasses
+
     from repro.core.compressor import CompressionConfig
-    from repro.engine.plan import ExecutionPlan
+    from repro.engine.plan import ExecutionPlan, SamplingPolicy
     from repro.graph.data import Graph
     from repro.graph.models import GNNConfig
     from repro.optim import AdamWConfig
 
     config, traffic = files["config"], files["traffic"]
-    if traffic["mode"] != "full":
-        raise ValueError(f"traffic mode {traffic['mode']!r}: only 'full' "
-                         "graph training is driven")
+    if traffic["mode"] not in ("full", "mesh"):
+        raise ValueError(f"traffic mode {traffic['mode']!r}: 'full' or "
+                         "'mesh' graph training is driven")
+    if traffic["mode"] == "mesh":
+        if traffic["n_parts"] != files["cell"]["chips"]:
+            raise ValueError(
+                f"a mesh traffic of {traffic['n_parts']} partitions on "
+                f"{files['cell']['chips']} chips: the check is full-graph "
+                "training, which only one partition a chip computes")
+        if matmul_precision(files) != "default":
+            raise ValueError("a mesh cell runs at the default matmul "
+                             "precision: its round step compiles at its "
+                             "first call")
     model, o = config["model"], config["optimizer"]
     if model["arch"] != "sage" or model["aggregation"] != "mean":
         raise ValueError(f"model {model}: the reference is mean-aggregation "
@@ -117,6 +152,10 @@ def program_objects(files: dict, graph: dict):
                                  rp_ratio=traffic["rp_ratio"],
                                  vm=traffic["vm"])
     plan = ExecutionPlan.from_legacy(fused=traffic["fused"])
+    if traffic["mode"] == "mesh":
+        plan = dataclasses.replace(plan, sampling=SamplingPolicy(
+            kind="mesh", n_parts=traffic["n_parts"],
+            method=traffic["method"]))
     cfg = plan.kernel.apply(GNNConfig(
         arch="sage", hidden=tuple(model["hidden"]),
         n_classes=config["graph"]["n_classes"], compression=comp))
@@ -144,14 +183,34 @@ def _host(tree):
 
 
 # ------------------------------------------------------------------- a run
+def _round_exe(comp, params, state, epoch: int):
+    """The mesh's round step, compiled for round 0's operands.  The plan
+    offers no public handle on it, so this reads the lowering's
+    ``_round_step`` and ``_round_const``; the same lowering's first call
+    finds this executable in JAX's cache and compiles nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    feats = jax.ShapeDtypeStruct(comp.prog.features.shape[1:], jnp.float32,
+                                 sharding=NamedSharding(comp.mesh,
+                                                        P("graph")))
+    return comp._round_step.lower(params, state, jnp.asarray(epoch),
+                                  jnp.asarray(0), feats,
+                                  *comp._round_const[0]).compile()
+
+
 def build(files: dict, seed: int):
     """Set-up up to the compiled step: returns a namespace with the
     graph, the weights (and a host copy), the program's objects, the
-    optimizer state and the compiled step."""
+    optimizer state, the compiled program (``exe``: the full graph's
+    epoch step, or the mesh's round step) and the plan's epoch step
+    (``step``: ``exe`` itself for a full graph)."""
     import types
 
     import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.engine.compile import compile_plan
     from repro.optim import adamw_init
@@ -170,13 +229,31 @@ def build(files: dict, seed: int):
     b.data = b.comp.epoch_data(None)
     b.epoch = first_epoch(seed)
     t = time.perf_counter()
-    precision = matmul_precision(files)
-    with (contextlib.nullcontext() if precision == "default"
-          else jax.default_matmul_precision(precision)):
-        b.exe = b.comp.step.lower(b.params, b.state, jnp.asarray(b.epoch),
-                                  *b.data).compile()
+    if b.comp.kind == "mesh":
+        b.params, b.state = jax.device_put(
+            (b.params, b.state), NamedSharding(b.comp.mesh, P()))
+        b.exe = _round_exe(b.comp, b.params, b.state, b.epoch)
+        b.step = b.comp.step
+    else:
+        precision = matmul_precision(files)
+        with (contextlib.nullcontext() if precision == "default"
+              else jax.default_matmul_precision(precision)):
+            b.exe = b.comp.step.lower(b.params, b.state,
+                                      jnp.asarray(b.epoch),
+                                      *b.data).compile()
+        b.step = b.exe
     b.compile_s = time.perf_counter() - t
     return b
+
+
+def node_chips(b):
+    """The chip each node's rows sit on (``None`` on one chip): partition
+    ``p`` trains on chip ``p % m``."""
+    if b.comp.kind != "mesh" or b.comp.m == 1:
+        return None
+    import numpy as np
+
+    return np.asarray(b.comp.prog.part % b.comp.m, np.int32)
 
 
 def first_steps(b) -> dict:
@@ -189,8 +266,8 @@ def first_steps(b) -> dict:
 
     prog = {"losses": []}
     for k in range(3):
-        b.params, b.state, loss = b.exe(b.params, b.state,
-                                        jnp.asarray(b.epoch), *b.data)
+        b.params, b.state, loss = b.step(b.params, b.state,
+                                         jnp.asarray(b.epoch), *b.data)
         b.epoch += 1
         prog["losses"].append(float(loss))
         if k == 0:
@@ -201,10 +278,12 @@ def first_steps(b) -> dict:
 
 
 def reference_run(files: dict, graph: dict, params0,
-                  control: bool = False) -> dict:
+                  control: bool = False, chips=None) -> dict:
     """The plain reference's first three steps from the same start, in
     the configuration's float32 at its matmul precision; with
-    ``control``, in bfloat16, the nearest precision below."""
+    ``control``, in bfloat16, the nearest precision below.  ``chips``
+    (the chip of each node, on a mesh) adds the norms of each chip's
+    share of the first weight gradient, for ``grad_scale_gap``."""
     import reference
 
     ref = {}
@@ -212,6 +291,10 @@ def reference_run(files: dict, graph: dict, params0,
         params0, graph, files["config"]["optimizer"],
         "bfloat16" if control else "float32",
         "default" if control else matmul_precision(files))
+    if chips is not None:
+        ref["chip_grad_norms"] = reference.chip_grad_norms(
+            params0, graph, chips, files["cell"]["chips"],
+            matmul_precision(files))
     return ref
 
 
@@ -230,14 +313,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if config_override is not None:
         files["config"] = config_override(files["config"])
     chips = files["cell"]["chips"]
-    devs = jax.devices()
-    if require_tpu:
-        if devs[0].platform != "tpu":
-            raise NoChip(f"JAX's first device is {devs[0].platform!r}, "
-                         "not a TPU")
-        if len(devs) < chips:
-            raise NoChip(f"the cell needs {chips} chips; JAX found "
-                         f"{len(devs)}")
+    devs = check_devices(chips, require_tpu)
     dev = devs[0]
     where = f"[{dev.platform} {dev.device_kind} x{len(devs)}]"
 
@@ -246,6 +322,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     step_bytes = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     hlo_text = b.exe.as_text()
+    chip_of = node_chips(b)
     prog = first_steps(b)
     setup_s = time.perf_counter() - t0
     log(f"setup {where}: setup_s={setup_s:.3f} import_s={t_jax - t0:.3f} "
@@ -255,8 +332,10 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         f"{mem.temp_size_in_bytes}, aliased {mem.alias_size_in_bytes})")
 
     # the window
-    exe, params, state, data, e = b.exe, b.params, b.state, b.data, b.epoch
-    del b.exe, b.params, b.state, b.data, b.comp
+    step, params, state, data, e = (b.step, b.params, b.state, b.data,
+                                    b.epoch)
+    comp = b.comp
+    del b.exe, b.step, b.params, b.state, b.data, b.comp
     tmp = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     losses = []
     with contextlib.ExitStack() as stack:
@@ -268,8 +347,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
             prev = None
             while True:
                 with jax.profiler.TraceAnnotation("bench.dispatch"):
-                    params, state, loss = exe(params, state, jnp.asarray(e),
-                                              *data)
+                    params, state, loss = step(params, state,
+                                               jnp.asarray(e), *data)
                 e += 1
                 losses.append(loss)
                 if prev is not None:
@@ -285,9 +364,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     failed = sum(not math.isfinite(float(v)) for v in losses)
     stats = [d.memory_stats() or {} for d in devs[:chips]]
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
+    extras = comp.result_extras()
     log(f"window {where}: epochs={epochs} wall_s={wall:.6f} "
         f"epoch_s={wall / epochs:.6f} step_hbm_gb={step_bytes / 1e9:.6f} "
-        f"peak_bytes_in_use={peak}")
+        f"peak_bytes_in_use={peak}"
+        + (f" plan {json.dumps(extras)}" if extras else ""))
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": int(peak)}
@@ -295,8 +376,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         try:
             result.update(_per_layer(spec, files, cell_name, tmp, hlo_text,
-                                     epochs, wall, dev, b.g, b.cfg, device,
-                                     log, where))
+                                     epochs, wall, dev, b.g, b.cfg, extras,
+                                     device, log, where))
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
     else:
@@ -308,9 +389,9 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         result["device"] = device
 
     # the check: the program's state is freed before the reference runs
-    del params, state, exe, data, losses, loss, prev
+    del params, state, step, comp, data, losses, loss, prev
     t_ref = time.perf_counter()
-    ref = reference_run(files, b.graph, b.params0)
+    ref = reference_run(files, b.graph, b.params0, chips=chip_of)
     read = verdict.readings(prog, ref, b.params0,
                             files["traffic"]["rp_ratio"] or 0)
     ok, checks = verdict.judge(read, files["limits"])
@@ -327,7 +408,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
 
 def _per_layer(spec, files, cell_name, tmp, hlo_text, epochs, wall, dev, g,
-               cfg, device, log, where) -> dict:
+               cfg, extras, device, log, where) -> dict:
     """The per-layer metrics of a traced window."""
     import glob
     import types
@@ -354,6 +435,7 @@ def _per_layer(spec, files, cell_name, tmp, hlo_text, epochs, wall, dev, g,
         cell=files["cell"], config=files["config"], traffic=files["traffic"],
         peaks=peakmod.chip_peaks(dev.device_kind), epochs=epochs,
         wall_s=wall, window=win, ops=ops, graph=g, gnn_cfg=cfg,
+        hlo_text=hlo_text, extras=extras,
         model_flops=counts.model_flops(
             files["config"]["graph"]["n_nodes"],
             graphgen.layer_dims(files["config"])))

@@ -151,20 +151,9 @@ class Scopes:
 
 
 def hlo_text_of(ctx) -> str | None:
-    """The compiled step's HLO text: ``ctx.hlo_text`` where the harness
-    gives it, else the ``hlo_text`` that ``harness._per_layer`` holds
-    while it calls the readers."""
-    text = getattr(ctx, "hlo_text", None)
-    if text is not None:
-        return text
-    frame = sys._getframe(1)
-    while frame is not None:
-        if (frame.f_code.co_name == "_per_layer"
-                and frame.f_globals.get("__name__") == "harness"):
-            text = frame.f_locals.get("hlo_text")
-            return text if isinstance(text, str) else None
-        frame = frame.f_back
-    return None
+    """The compiled step's HLO text, which the harness gives as
+    ``ctx.hlo_text``."""
+    return getattr(ctx, "hlo_text", None)
 
 
 def split(ctx) -> dict | None:
@@ -190,7 +179,7 @@ def split(ctx) -> dict | None:
     busy = ctx.window.busy_s
     if out is not None and busy > 0:
         print("scopes: " + " ".join(
-            f"{s}_ms={1e3 * out[s] / max(ctx.epochs, 1):.3f}"
+            f"{s}_ms={1e3 * out[s] / max(ctx.epochs, 1) / chips(ctx):.3f}"
             for s in SCOPES)
             + f" unscoped_share={100 * out[None] / busy:.3f}%"
             f" mixed_fusion_share={100 * out['mixed'] / busy:.3f}%",
@@ -198,11 +187,18 @@ def split(ctx) -> dict | None:
     return out
 
 
+def chips(ctx) -> int:
+    """The chips of the traced cell, over which the window's device
+    seconds are summed."""
+    cell = getattr(ctx, "cell", None)
+    return cell["chips"] if cell else 1
+
+
 def ms_per_epoch(ctx, *names) -> float | None:
-    """Device milliseconds per epoch in the named scopes, or ``None``
-    where they hold no device time."""
+    """Device milliseconds per epoch in the named scopes, averaged over
+    the chips, or ``None`` where they hold no device time."""
     got = split(ctx)
     if got is None or ctx.epochs == 0:
         return None
     s = sum(got[n] for n in names)
-    return 1e3 * s / ctx.epochs if s > 0 else None
+    return 1e3 * s / ctx.epochs / chips(ctx) if s > 0 else None

@@ -63,10 +63,17 @@ def test_cell_files_load_by_name(cell):
     config, traffic = files["config"], files["traffic"]
     entry = {c["name"]: c for c in SPEC["configs"]}[files["cell"]["config"]]
     assert config["name"] == entry["name"]
-    assert config["reduced"] == entry["reduced"] == []
+    assert config["reduced"] == entry["reduced"]
+    assert set(config["reduced"]) <= set(config.get("published", {}))
     gr = config["graph"]
     assert gr["directed_edges"] == 2 * gr["n_edges"] + gr["n_nodes"]
-    assert traffic["mode"] == "full"
+    assert traffic["mode"] in ("full", "mesh")
+    if traffic["mode"] == "mesh":
+        # one partition a chip: every edge kept, full-graph training
+        assert traffic["n_parts"] == files["cell"]["chips"]
+        assert traffic["method"] in ("bfs", "random")
+    else:
+        assert "n_parts" not in traffic and "method" not in traffic
     assert set(files["limits"]) and all(v > 0 for v in
                                         files["limits"].values())
     assert {m["name"] for m in harness.metrics_of(SPEC, cell,

@@ -33,10 +33,46 @@ def _small(config):
     return config
 
 
-def _run(cell, seed=2**31 + 11):
-    return harness.run(cell, seed, 0.2, False, t0=time.perf_counter(),
-                       require_tpu=False, config_override=_small,
-                       log=lambda msg: None)
+def _run(cell, fault=None, seed=2**31 + 11):
+    """A short run of ``cell`` at a test's size, with ``fault`` (a key
+    of :data:`FAULTS`) planted; a cell of several chips runs in a child
+    with that many virtual CPU devices."""
+    chips = harness.cell_files(SPEC, cell)["cell"]["chips"]
+    if chips > 1:
+        return _run_in_child(cell, fault, seed, chips)
+    return _run_here(cell, fault, seed)
+
+
+def _run_here(cell, fault, seed):
+    """:func:`_run` in this process, on its devices."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        if fault is not None:
+            FAULTS[fault](monkeypatch)
+        return harness.run(cell, seed, 0.2, False, t0=time.perf_counter(),
+                           require_tpu=False, config_override=_small,
+                           log=lambda msg: None)
+
+
+#: The child of :func:`_run_in_child`: argv is the tests' directory,
+#: ``src``, the cell, the fault ("" for none) and the seed.
+CHILD = r"""
+import json, sys
+sys.path[:0] = sys.argv[1:3]
+import test_bench_run
+print(json.dumps(test_bench_run._run_here(sys.argv[3], sys.argv[4] or None,
+                                          int(sys.argv[5]))))
+"""
+
+
+def _run_in_child(cell, fault, seed, devices):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    out = subprocess.run(
+        [sys.executable, "-c", CHILD, str(BENCH / "tests"),
+         str(ROOT / "src"), cell, fault or "", str(seed)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def _command(cwd, tmp_path):
@@ -102,19 +138,17 @@ def _state_unchanged(monkeypatch):
 
 def _planted(name):
     def plant(monkeypatch):
-        import importlib
-
         import calibrate
 
-        module, attr, wrap = calibrate.FAULTS[name]
-        mod = importlib.import_module(module)
-        monkeypatch.setattr(mod, attr, wrap(getattr(mod, attr)))
+        for mod, attr, value in list(calibrate.plants(name)):
+            monkeypatch.setattr(mod, attr, value)
     return plant
 
 
 FAULTS = {"state_unchanged": _state_unchanged,
           "half_batch": _planted("half_batch"),
-          "dequant_scale": _planted("dequant_scale")}
+          "dequant_scale": _planted("dequant_scale"),
+          "halo_dropped": _planted("halo_dropped")}
 #: (cell, fault) for every fault a cell's file says its limits catch.
 CAUGHT = [(cell, fault) for cell in CELLS
           for fault in harness.cell_files(SPEC, cell)["faults_caught"]]
@@ -140,7 +174,14 @@ def test_sound_compressed_run_is_correct(cell):
 
 @pytest.mark.parametrize("cell,fault", CAUGHT,
                          ids=[f"{c}-{f}" for c, f in CAUGHT])
-def test_planted_fault_is_not_correct(cell, fault, monkeypatch):
-    FAULTS[fault](monkeypatch)
-    r = _run(cell)
+def test_planted_fault_is_not_correct(cell, fault):
+    r = _run(cell, fault)
     assert not r["correct"], r["checks"]
+
+
+def test_a_child_run_plants_its_fault():
+    """The route a cell of several chips takes: the run, and its planted
+    fault, in a child process with four virtual devices."""
+    cell = "sage-arxiv.fp32"
+    assert _run_in_child(cell, None, 2**31 + 11, 4)["correct"]
+    assert not _run_in_child(cell, "half_batch", 2**31 + 11, 4)["correct"]

@@ -218,7 +218,7 @@ def _through_harness(rec, hlo, monkeypatch, tmp_path):
     lo, hi = rec["window"]
     out = harness._per_layer(spec, files, CELL, str(tmp_path), hlo,
                              rec["epochs"], (hi - lo) * 1e-9, dev, None,
-                             None, {}, lambda msg: None, "[test]")
+                             None, {}, {}, lambda msg: None, "[test]")
     return {k: v["value"] for k, v in out["metrics"].items()}
 
 
@@ -232,8 +232,8 @@ def test_harness_reads_old_metrics_alone_without_scopes(
 
 
 def test_harness_reads_the_scopes(scoped_fixture, monkeypatch, tmp_path):
-    """Through the harness as it stands, which gives the readers no
-    ``hlo_text``: they find the text ``_per_layer`` holds."""
+    """Through the harness as it stands, which gives the readers the
+    compiled step's ``hlo_text``."""
     rec, hlo = scoped_fixture
     got = _through_harness(rec, hlo, monkeypatch, tmp_path)
     ctx = _ctx(rec, hlo, hlo_text=hlo)

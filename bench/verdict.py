@@ -20,7 +20,10 @@ graph and weights:
   Rademacher ``P = R R^T / r`` (``D x r``, ``r = D / rp``) shared by all
   rows, and ``E |P G|^2 = (1 + (D - 1) / r) |G|^2``.  It reads the
   compression route's scale, which ``grad_gap`` cannot where RP noise
-  dominates;
+  dominates.  Where each of ``m`` chips draws its own ``P_i`` for its
+  rows' share ``G_i`` and the shares are summed, ``E |sum P_i G_i|^2 =
+  |G|^2 + (D - 1) / r * sum |G_i|^2``: the reference then gives the
+  shares' norms (``chip_grad_norms``);
 * ``update_gap`` — the parameters' change over the three updates: the
   worst leaf's gap between the two norms.
 
@@ -87,10 +90,26 @@ def details(prog: dict, ref: dict, params0) -> dict:
                          for a, b in zip(g_prog, g_ref)]}
 
 
-def rp_scale(rows: int, rp: int) -> float:
-    """The expected norm ratio ``sqrt(1 + (D - 1) / r)`` that an RP of
-    ratio ``rp`` over ``D = rows`` gives a weight gradient; 1 without."""
-    return float(np.sqrt(1 + (rows - 1) / (rows // rp))) if rp > 1 else 1.0
+def rp_scale(rows: int, rp: int, share: float = 1.0) -> float:
+    """The expected norm ratio ``sqrt(1 + (D - 1) / r * share)`` that an
+    RP of ratio ``rp`` over ``D = rows`` gives a weight gradient; 1
+    without.  ``share`` is ``sum |G_i|^2 / |G|^2`` over chips that draw
+    their projections apart (1 for one projection)."""
+    if rp <= 1:
+        return 1.0
+    return float(np.sqrt(1 + (rows - 1) / (rows // rp) * share))
+
+
+def _shares(ref: dict, rn, params0) -> list[float]:
+    """``sum |G_i|^2 / |G|^2`` of each weight leaf, from the reference's
+    ``chip_grad_norms`` (one list of per-chip norms a layer) where it
+    has them; 1 each otherwise."""
+    parts = ref.get("chip_grad_norms")
+    weights = [i for i, a in enumerate(_leaves(params0)) if a.ndim == 2]
+    if parts is None:
+        return [1.0] * len(weights)
+    return [float(np.sum(np.square(p)) / max(rn[i] ** 2, 1e-30))
+            for p, i in zip(parts, weights)]
 
 
 def readings(prog: dict, ref: dict, params0, rp: int = 0) -> dict:
@@ -103,8 +122,9 @@ def readings(prog: dict, ref: dict, params0, rp: int = 0) -> dict:
     bias, weight = is_bias & counted, ~is_bias & counted
     grad, upd = np.array(d["grad_gaps"]), np.array(d["update_gaps"])
     gn, rn = (np.array(x) for x in d["grad_norms"])
-    scale = np.array([rp_scale(a.shape[0], rp) if a.ndim == 2 else 1.0
-                      for a in _leaves(params0)])
+    shares = iter(_shares(ref, rn, params0))
+    scale = np.array([rp_scale(a.shape[0], rp, next(shares))
+                      if a.ndim == 2 else 1.0 for a in _leaves(params0)])
     floor = np.median(rn[counted])
     scaled = np.abs(gn - scale * rn) / np.maximum(scale * rn, floor)
     return {"loss1_gap": d["loss_gaps"][0],
